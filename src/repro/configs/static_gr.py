@@ -39,6 +39,7 @@ SID_VOCAB = 2048
 SID_LENGTH = 8
 DENSE_D = 2
 N_CONSTRAINTS = 20_000_000  # "fresh video" corpus of §5.2
+BATCH_PER_CHIP = 2  # requests per chip (App. B: 512 global over 256 chips)
 
 SHAPES = (
     GRShape("gr_train", "train", global_batch=1024),

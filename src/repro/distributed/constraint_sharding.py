@@ -49,7 +49,6 @@ from repro.decoding.backends import StackedStaticBackend, StaticBackend
 from repro.distributed.sharding import (
     dp_axes,
     dp_size,
-    shard_map_compat,
     tree_shardings,
 )
 
@@ -671,7 +670,7 @@ def spmd_beam_search(
             )
             return state.tokens, state.scores
 
-        fn = jax.jit(shard_map_compat(
+        fn = jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(specs, P(dp)) if have_ids else (specs,),
             out_specs=(P(dp, None, None), P(dp, None)),

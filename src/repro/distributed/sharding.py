@@ -35,21 +35,7 @@ __all__ = [
     "dp_axes", "dp_size", "model_size", "ns", "replicated",
     "lm_param_pspecs", "lm_batch_pspec", "kv_cache_pspecs",
     "recsys_param_pspecs", "gnn_param_pspecs", "tree_shardings",
-    "shard_map_compat",
 ]
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` across JAX versions (same spirit as ``make_mesh_compat``).
-
-    ``jax.shard_map`` is the stable home from 0.6; earlier installs (this
-    repo's floor is 0.4.x) only have ``jax.experimental.shard_map``, which
-    newer releases removed.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
 
 
 def dp_axes(mesh: Mesh):

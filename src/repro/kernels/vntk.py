@@ -3,20 +3,25 @@
 TPU-native adaptation of the paper's GPU-friendly gather/scatter formulation
 (see DESIGN.md §3):
 
-  * Phase 1/2 (boundary lookup + speculative slicing) become explicit
-    HBM->VMEM **async DMAs** of the stacked ``(B_l, 2)`` edge slab — the
-    "single coalesced memory transaction" of paper §A.1.1 made literal.
+  * Phase 1/2 (boundary lookup + speculative slicing) run in XLA in front of
+    the kernel: one gather of each beam's row bounds and one gather of its
+    ``bmax``-slot burst from the edge slab — ``nb * bmax`` elements, the
+    paper's "single coalesced memory transaction".  The kernel never DMAs
+    the slab itself: a burst starts at an arbitrary edge offset, and Mosaic
+    only slices HBM at tile granularity (1024 elements for a 1-D slab, 8x128
+    for a 2-D one), so a per-beam DMA of the raw burst does not compile.
   * Phase 4 (scatter projection) becomes a **compare-broadcast reduction**:
     ``mask[v] = any_j (cols[j] == v & j < n_child)``.  TPUs have no efficient
     VMEM scatter; an elementwise compare over the lane-aligned vocab axis is
     branch-free and VPU-friendly.  Next-state ids are produced vocab-aligned
-    by the same reduction (``sum_j hit[j] * next[j]`` — token columns within
-    a CSR row are unique, so the sum has at most one non-zero term).
+    by the same reduction (token columns within a CSR row are unique, so at
+    most one slot hits each column).
 
-Slots are processed in fixed chunks through a ``fori_loop`` so VMEM pressure
-stays at ``O(beam_tile * vocab)`` regardless of the branch factor, and the
-edge DMA length is the chunk-rounded branch factor (the edges tensor is
-padded accordingly by the trie builder).
+Slots stream through a ``fori_loop`` one at a time, so VMEM holds
+``O(beam_tile * (V + bmax))`` for any branch factor.  Every value in the
+kernel is a 2-D ``(beam_tile, lanes)`` array; slot ``j`` of a row-major slot
+array is read with a one-hot lane reduction (:func:`_lane`), because the TPU
+has no dynamic lane indexing.
 
 The fused variant additionally normalizes raw logits with an in-register
 log-softmax before masking, eliminating one full HBM round-trip over the
@@ -25,26 +30,22 @@ log-softmax before masking, eliminating one full HBM round-trip over the
 The **candidate-compressed** kernels (``vntk_topk_pallas`` /
 ``vntk_stacked_topk_pallas``, DESIGN.md §8) go one step further: instead of
 writing the vocab-aligned ``(nb, V)`` masked log-probs *and* next-state map
-back to HBM, they select each beam's dense-rank top-``C`` **in VMEM** — via
-the same compare-broadcast machinery, now reducing over the vocab axis to
-gather candidate log-probs — and emit only ``(nb, C)`` scores/tokens/states.
-HBM write traffic per step drops from ``O(nb * V)`` to ``O(nb * C)``.
-Selection is a branch-free rank-by-counting pass (TPUs have no in-VMEM sort):
-``rank[j] = #{j' : key[j'] > key[j] or (key[j'] == key[j] and j' < j)}``
-followed by a compare-broadcast scatter into the ``C`` output lanes; the
-index tie-break reproduces the dense path's flat-index tie order exactly
-(candidate slots are token-ascending, see ``core.vntk._topk_from_candidates``).
+back to HBM, they select each beam's dense-rank top-``C`` **in VMEM** and
+emit only ``(nb, C)`` scores/tokens/states.  HBM write traffic per step drops
+from ``O(nb * V)`` to ``O(nb * C)``.  Selection is a branch-free
+rank-by-counting pass (TPUs have no in-VMEM sort) over the valid children
+plus the smallest missing tokens at NEG_INF (the dense tie-break's
+invalid-continuation order); the index tie-break reproduces the dense path's
+flat-index tie order exactly (candidate slots are token-ascending, see
+``core.vntk._topk_from_candidates``).
 
-The **compressed-slab** kernels (``vntk_compressed_*``, DESIGN.md §11) swap
-the ``(E, 2)`` int32 edge slab for the delta-encoded token array of
-:class:`repro.core.compressed_slab.CompressedSlab` — int16 where the vocab
-permits — so the speculative burst moves 2 B/slot over the DMA instead of
-8 B.  Decompression is fused into the same wave: an int32 cumsum over the
-burst (which always begins at a CSR row start, so the absolute anchor is
-slot 0) recovers the token columns, and next states are rebuilt as
-``row_start + slot + level_base`` with the per-beam base arriving as a tiny
-blocked input.  Everything downstream of the decode is the shared
-projection/selection machinery, so outputs are bit-identical to the
+The **compressed-slab** entry points (``vntk_compressed_*``, DESIGN.md §11)
+gather the delta-encoded token burst of
+:class:`repro.core.compressed_slab.CompressedSlab` (int16 where the vocab
+permits) instead of the ``(E, 2)`` int32 edge slab and decompress it in the
+same XLA front — an int32 cumsum over the burst recovers the token columns,
+and next states are ``row_start + slot + level_base``.  Everything after the
+front is the shared kernel, so outputs are bit-identical to the
 uncompressed kernels.
 """
 from __future__ import annotations
@@ -54,9 +55,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1.0e10
+LANES = 128
 
 __all__ = [
     "vntk_pallas",
@@ -80,836 +81,259 @@ def _beam_padding(nb: int, beam_tile: int) -> tuple[int, int]:
     """Grid tiling for ``nb`` beam rows: ``(beam_tile, nb_padded)``.
 
     The beam axis is padded UP to a tile multiple instead of degrading the
-    tile (the old ``while nb % beam_tile: beam_tile -= 1`` walked prime row
-    counts all the way down to tile=1, serializing the whole grid).  Pad rows
-    decode from the SINK state (node 0, an empty CSR row) so their DMAs stay
-    in bounds and their outputs are sliced away by the caller.
+    tile (walking a prime row count down to tile=1 would serialize the whole
+    grid).  Pad rows have no children, so they mask everything and are
+    sliced away by the caller.
     """
     beam_tile = max(1, min(beam_tile, nb))
     return beam_tile, _round_up(nb, beam_tile)
 
 
-def _pad_rows(arr, nb_padded: int, fill=0):
-    """Pad axis 0 of ``arr`` to ``nb_padded`` rows with ``fill``."""
+def _pad_rows(arr, nb_padded: int):
+    """Pad axis 0 of ``arr`` to ``nb_padded`` rows with zeros."""
     nb = arr.shape[0]
     if nb == nb_padded:
         return arr
-    pad = [(0, nb_padded - nb)] + [(0, 0)] * (arr.ndim - 1)
-    return jnp.pad(arr, pad, constant_values=fill)
+    return jnp.pad(arr, [(0, nb_padded - nb)] + [(0, 0)] * (arr.ndim - 1))
 
 
-def _dma_front(
-    nodes_ref,
-    rowptr_hbm,
-    edges_hbm,
-    rp_scratch,
-    edge_scratch,
-    sem_rp,
-    sem_edge,
-    *,
-    beam_tile: int,
-    bmax_padded: int,
-    cids_ref=None,
-):
-    """Phases 1+2: pipelined per-beam boundary lookup + speculative burst.
-
-    Two overlapped waves: ALL row-pointer copies are issued before any is
-    waited on, so beam i+1's rowptr fetch rides under beam i's edge burst
-    (the old inline start()+wait() serialized the whole front: no rowptr
-    DMA could overlap anything).  Edge bursts still wait on their own beam's
-    row pointer — the burst start address depends on it, which is why
-    ``sem_rp`` is a PER-BEAM semaphore array: a shared DMA semaphore counts
-    completions without identifying which copy signaled, so beam j landing
-    first could otherwise unblock beam i's wait while beam i's row pointer
-    is still in flight.  The edge wave may share one semaphore — nothing
-    reads ``edge_scratch`` until every edge wait has returned, and
-    ``beam_tile`` waits can only be satisfied by ``beam_tile`` completions.
-    With ``cids_ref`` both tensors carry a leading constraint axis (stacked
-    store, §4).  The front is shape-agnostic in the trailing slot layout:
-    the same two waves move the raw ``(slot, 2)`` int32 burst or the
-    compressed slab's flat int16/int32 delta burst (§11) — only the scratch
-    destination's shape/dtype differ.
-    """
-    def rp_src(i):
-        sl = pl.ds(nodes_ref[i], 2)
-        return (rowptr_hbm.at[cids_ref[i], sl] if cids_ref is not None
-                else rowptr_hbm.at[sl])
-
-    def edge_src(i, start):
-        sl = pl.ds(start, bmax_padded)
-        return (edges_hbm.at[cids_ref[i], sl] if cids_ref is not None
-                else edges_hbm.at[sl])
-
-    rp_copies = [
-        pltpu.make_async_copy(rp_src(i), rp_scratch.at[i], sem_rp.at[i])
-        for i in range(beam_tile)
-    ]
-    for cp in rp_copies:
-        cp.start()
-    edge_copies = []
-    for i in range(beam_tile):
-        rp_copies[i].wait()  # semaphore i: signaled only by copy i
-        cp2 = pltpu.make_async_copy(
-            edge_src(i, rp_scratch[i, 0]), edge_scratch.at[i], sem_edge
-        )
-        cp2.start()
-        edge_copies.append(cp2)
-    for cp2 in edge_copies:
-        cp2.wait()
-
-
-def _decode_delta_slots(rp_scratch, tok_scratch, base_ref):
-    """Fused slab decompression (DESIGN.md §11): delta burst -> slot arrays.
-
-    The burst in ``tok_scratch`` starts at this beam's CSR row start, whose
-    delta IS the absolute token, so one int32 cumsum along the slot axis
-    recovers every column (the cast happens BEFORE the cumsum: int16 partial
-    sums would wrap for vocabularies near the int16 limit).  Slots past the
-    row end decode to garbage exactly like the uncompressed speculative
-    over-read — the shared ``iota < n_child`` sanitization masks both.  Next
-    states need no stored bytes at all: destinations are consecutive over
-    each level's edge block, so ``next = row_start + slot + level_base``.
-    """
-    beam_tile, bmax_padded = tok_scratch.shape
-    n_child = rp_scratch[:, 1] - rp_scratch[:, 0]  # (beam_tile,)
-    cols_all = jnp.cumsum(tok_scratch[...].astype(jnp.int32), axis=1)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (beam_tile, bmax_padded), 1)
-    next_all = rp_scratch[:, 0][:, None] + iota + base_ref[...][:, None]
-    return n_child, cols_all, next_all
-
-
-def _raw_slots(rp_scratch, edge_scratch):
-    """Slot arrays of the uncompressed ``(beam_tile, bmax_padded, 2)`` burst."""
-    n_child = rp_scratch[:, 1] - rp_scratch[:, 0]  # (beam_tile,)
-    return n_child, edge_scratch[:, :, 0], edge_scratch[:, :, 1]
-
-
-def _project_and_write(
-    n_child,
-    cols_all,
-    next_all,
-    logits_ref,
-    out_lp_ref,
-    out_next_ref,
-    *,
-    bmax_padded: int,
-    slot_chunk: int,
-    vocab: int,
-    beam_tile: int,
-    fused_logsoftmax: bool,
-):
-    """Phases 3+4 (+ optional fused log-softmax): shared by all DMA fronts.
-
-    Consumes the decoded slot arrays ``(n_child, cols_all, next_all)`` so the
-    same projection serves both the raw ``(slot, 2)`` burst and the
-    delta-decompressed compressed slab."""
-    # ---- Phase 3+4: chunked sanitize + compare-broadcast projection ----
-    n_chunks = bmax_padded // slot_chunk
-    iota_slot = jax.lax.broadcasted_iota(jnp.int32, (beam_tile, slot_chunk), 1)
-    iota_v = jax.lax.broadcasted_iota(
-        jnp.int32, (beam_tile, slot_chunk, vocab), 2
-    )
-
-    def chunk_body(c, carry):
-        mask, nxt = carry
-        cols = jax.lax.dynamic_slice_in_dim(
-            cols_all, c * slot_chunk, slot_chunk, axis=1
-        )
-        vals = jax.lax.dynamic_slice_in_dim(
-            next_all, c * slot_chunk, slot_chunk, axis=1
-        )
-        valid = (c * slot_chunk + iota_slot) < n_child[:, None]
-        hit = (cols[:, :, None] == iota_v) & valid[:, :, None]
-        mask = mask | jnp.any(hit, axis=1)
-        nxt = nxt + jnp.sum(
-            hit.astype(jnp.int32) * vals[:, :, None], axis=1, dtype=jnp.int32
-        )
-        return mask, nxt
-
-    mask0 = jnp.zeros((beam_tile, vocab), bool)
-    nxt0 = jnp.zeros((beam_tile, vocab), jnp.int32)
-    mask, nxt = jax.lax.fori_loop(0, n_chunks, chunk_body, (mask0, nxt0))
-
-    x = logits_ref[...]
-    if fused_logsoftmax:
-        xf = x.astype(jnp.float32)
-        m = jnp.max(xf, axis=-1, keepdims=True)
-        lse = jnp.log(jnp.sum(jnp.exp(xf - m), axis=-1, keepdims=True))
-        lp = (xf - m - lse).astype(out_lp_ref.dtype)
+# ---------------------------------------------------------------------------
+# Phases 1+2 (XLA front): row bounds and the speculative burst
+# ---------------------------------------------------------------------------
+def _burst(nodes, cids, row_pointers, bmax: int):
+    """Each row's child count and the edge index of each of its ``bmax``
+    speculative slots.  With ``cids`` the row pointers carry a leading
+    constraint axis (stacked store, §4)."""
+    if cids is None:
+        starts = row_pointers[nodes]
+        n_child = row_pointers[nodes + 1] - starts
     else:
-        lp = x.astype(out_lp_ref.dtype)
-    out_lp_ref[...] = jnp.where(mask, lp, jnp.asarray(NEG_INF, out_lp_ref.dtype))
+        starts = row_pointers[cids, nodes]
+        n_child = row_pointers[cids, nodes + 1] - starts
+    idx = starts[:, None] + jnp.arange(bmax, dtype=starts.dtype)[None, :]
+    return n_child, idx
+
+
+def _raw_slots(edges, cids, idx):
+    """Slot arrays ``(cols, next)`` of the ``(E, 2)`` / ``(K, E, 2)`` slab."""
+    e = edges[idx] if cids is None else edges[cids[:, None], idx]
+    return e[..., 0], e[..., 1]
+
+
+def _delta_slots(tok_delta, cids, idx, base):
+    """Slot arrays of the compressed slab (DESIGN.md §11).
+
+    The burst starts at the row start, whose delta IS the absolute token, so
+    one int32 cumsum recovers every column (the cast comes BEFORE the cumsum:
+    int16 partial sums would wrap for vocabularies near the int16 limit).
+    Next states need no stored bytes: ``next = edge_index + level_base``.
+    Slots past the row end decode to garbage exactly like the uncompressed
+    speculative over-read — the kernel's ``slot < n_child`` test masks both.
+    """
+    d = tok_delta[idx] if cids is None else tok_delta[cids[:, None], idx]
+    return jnp.cumsum(d.astype(jnp.int32), axis=1), idx + base[:, None]
+
+
+# ---------------------------------------------------------------------------
+# Phases 3+4 (the kernel)
+# ---------------------------------------------------------------------------
+def _lane(x, j):
+    """Column ``j`` (traced) of a ``(rows, lanes)`` value as ``(rows, 1)``.
+
+    A one-hot max over the lanes: exact for every value (``-inf`` included),
+    and it needs no dynamic lane indexing, which the TPU does not have."""
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        low = jnp.asarray(-jnp.inf, x.dtype)
+    else:
+        low = jnp.asarray(jnp.iinfo(x.dtype).min, x.dtype)
+    iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.max(jnp.where(iota == j, x, low), axis=1, keepdims=True)
+
+
+def _log_probs(x_ref, fused_logsoftmax: bool):
+    x = x_ref[...].astype(jnp.float32)
+    if not fused_logsoftmax:
+        return x
+    m = jnp.max(x, axis=-1, keepdims=True)
+    lse = jnp.log(jnp.sum(jnp.exp(x - m), axis=-1, keepdims=True))
+    return x - m - lse
+
+
+def _mask_body(n_ref, cols_ref, next_ref, x_ref, out_lp_ref, out_next_ref, *,
+               n_slots: int, fused_logsoftmax: bool):
+    """Vocab-aligned ``(masked log-probs, next states)`` for one beam tile."""
+    cols, nexts, n_child = cols_ref[...], next_ref[...], n_ref[...]
+    shape = out_lp_ref.shape
+    iota_v = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+
+    def slot(j, carry):
+        mask, nxt = carry
+        hit = (iota_v == _lane(cols, j)) & (j < n_child)
+        return jnp.where(hit, 1, mask), jnp.where(hit, _lane(nexts, j), nxt)
+
+    zeros = jnp.zeros(shape, jnp.int32)
+    mask, nxt = jax.lax.fori_loop(0, n_slots, slot, (zeros, zeros))
+    lp = _log_probs(x_ref, fused_logsoftmax).astype(out_lp_ref.dtype)
+    out_lp_ref[...] = jnp.where(
+        mask != 0, lp, jnp.asarray(NEG_INF, out_lp_ref.dtype))
     out_next_ref[...] = nxt
 
 
-def _project_and_select(
-    n_child,
-    cols_all,
-    next_all,
-    logits_ref,
-    out_sc_ref,
-    out_tok_ref,
-    out_next_ref,
-    *,
-    bmax_padded: int,
-    slot_chunk: int,
-    vocab: int,
-    beam_tile: int,
-    width: int,
-    fused_logsoftmax: bool,
-):
-    """Phases 3+4' of the candidate-compressed step (DESIGN.md §8).
+def _select_body(n_ref, cols_ref, next_ref, x_ref, out_sc_ref, out_tok_ref,
+                 out_next_ref, *, n_slots: int, vocab: int,
+                 fused_logsoftmax: bool):
+    """Candidate-compressed step (DESIGN.md §8) for one beam tile.
 
-    Instead of projecting the candidates to a vocab-aligned mask, the same
-    chunked compare-broadcast now runs the OTHER way — reducing over the
-    vocab axis to gather each CSR slot's log-prob — and an in-VMEM
-    rank-by-counting pass selects each beam's dense-rank top-``width``:
-    valid children by (lp desc, token asc), then the smallest missing tokens
-    at NEG_INF (the dense tie-break's invalid-continuation order), exactly
-    as in :func:`repro.core.vntk._topk_from_candidates`.  Only the
-    ``(beam_tile, width)`` winners ever leave VMEM.  Like
-    :func:`_project_and_write` it consumes decoded slot arrays, serving both
-    the raw and the compressed DMA fronts.
+    Each beam's dense-rank top-``C`` over its valid children, by (lp desc,
+    token asc), then the smallest missing tokens at NEG_INF — exactly
+    :func:`repro.core.vntk._topk_from_candidates`.  Only the
+    ``(beam_tile, C)`` winners leave VMEM.
+
+    The missing-token fills need no rank pass: all of them tie at NEG_INF
+    and rank after every child (ties go to the lower index), so fill ``i``
+    lands at output lane ``a + i``, where ``a`` counts the children whose
+    log-prob is ``>= NEG_INF``.  A child's rank is the number of children
+    that beat it plus, when its log-prob is below NEG_INF, the number of
+    in-range fills among the first ``C``.
     """
-    x = logits_ref[...]
-    xf = x.astype(jnp.float32)
-    if fused_logsoftmax:
-        m = jnp.max(xf, axis=-1, keepdims=True)
-        lse = jnp.log(jnp.sum(jnp.exp(xf - m), axis=-1, keepdims=True))
-        lp = xf - m - lse
-    else:
-        lp = xf
-
-    # ---- candidate log-prob gather: chunked compare-broadcast reduction ----
-    n_chunks = bmax_padded // slot_chunk
-    iota_slot = jax.lax.broadcasted_iota(jnp.int32, (beam_tile, slot_chunk), 1)
-    iota_v = jax.lax.broadcasted_iota(
-        jnp.int32, (beam_tile, slot_chunk, vocab), 2
-    )
-
-    def chunk_body(c, cand):
-        cols = jax.lax.dynamic_slice_in_dim(
-            cols_all, c * slot_chunk, slot_chunk, axis=1
-        )
-        valid = (c * slot_chunk + iota_slot) < n_child[:, None]
-        hit = (cols[:, :, None] == iota_v) & valid[:, :, None]
-        # token columns within a CSR row are unique: <= 1 non-zero term
-        vals = jnp.sum(hit.astype(jnp.float32) * lp[:, None, :], axis=2)
-        return jax.lax.dynamic_update_slice(cand, vals, (0, c * slot_chunk))
-
-    cand_lp = jax.lax.fori_loop(
-        0, n_chunks, chunk_body,
-        jnp.zeros((beam_tile, bmax_padded), jnp.float32),
-    )
-
-    # ---- per-beam dense-rank top-C over candidates + missing-token fill ----
+    lp = _log_probs(x_ref, fused_logsoftmax)
+    cols, nexts, n_child = cols_ref[...], next_ref[...], n_ref[...]
+    width = out_sc_ref.shape[1]
+    iota_v = jax.lax.broadcasted_iota(jnp.int32, lp.shape, 1)
+    iota_s = jax.lax.broadcasted_iota(jnp.int32, cols.shape, 1)
+    iota_c = jax.lax.broadcasted_iota(jnp.int32, out_sc_ref.shape, 1)
     minf = jnp.float32(jnp.finfo(jnp.float32).min)
-    iota_full = jax.lax.broadcasted_iota(
-        jnp.int32, (beam_tile, bmax_padded), 1
-    )
-    valid_full = iota_full < n_child[:, None]
-    real_key = jnp.where(valid_full, cand_lp, minf)
-    real_tok = jnp.where(valid_full, cols_all, 0)
-    real_next = jnp.where(valid_full, next_all, 0)
+    neg = jnp.float32(NEG_INF)
 
-    # i-th missing token = i + |{j : cols[j] - j <= i}| (sorted distinct cols)
-    adj = jnp.where(valid_full, cols_all - iota_full, vocab + bmax_padded + 1)
-    iota_c = jax.lax.broadcasted_iota(jnp.int32, (beam_tile, width), 1)
-    cnt = jnp.sum(
-        (adj[:, None, :] <= iota_c[:, :, None]).astype(jnp.int32), axis=2
-    )
-    fill_tok = iota_c + cnt
-    in_range = fill_tok < vocab
-    fill_key = jnp.where(in_range, jnp.float32(NEG_INF), minf)
-    fill_tok = jnp.where(in_range, fill_tok, 0)
+    # candidate log-prob of every slot (minf where the slot is no child)
+    def gather(j, keys):
+        lp_j = jnp.max(jnp.where(iota_v == _lane(cols, j), lp, -jnp.inf),
+                       axis=1, keepdims=True)
+        return jnp.where((iota_s == j) & (j < n_child), lp_j, keys)
 
-    keys = jnp.concatenate([real_key, fill_key], axis=1)  # (beam_tile, J)
-    toks = jnp.concatenate([real_tok, fill_tok], axis=1)
-    nxts = jnp.concatenate(
-        [real_next, jnp.zeros((beam_tile, width), next_all.dtype)], axis=1
-    )
-    J = bmax_padded + width
+    keys = jax.lax.fori_loop(
+        0, n_slots, gather, jnp.full(cols.shape, minf, jnp.float32))
 
-    # rank[j] = #{j' : key[j'] > key[j] or (== and j' < j)} — branch-free
-    # selection sort rank; the index tie-break IS the dense flat-index tie
-    # order (slots are token-ascending).  The competitor axis is chunked so
-    # VMEM stays O(J * chunk) rather than O(J^2).
-    idx_j = jax.lax.broadcasted_iota(jnp.int32, (beam_tile, J), 1)
-    ka = keys[:, :, None]
-    ia = idx_j[:, :, None]
-    rank = jnp.zeros((beam_tile, J), jnp.int32)
-    rchunk = max(slot_chunk * 16, width)
-    for c0 in range(0, J, rchunk):
-        c1 = min(c0 + rchunk, J)
-        kb = keys[:, None, c0:c1]
-        ib = idx_j[:, None, c0:c1]
-        beats = (kb > ka) | ((kb == ka) & (ib < ia))
-        rank = rank + jnp.sum(beats.astype(jnp.int32), axis=2)
+    # fills: i-th missing token = i + |{j : cols[j] - j <= i}|
+    n_fill = jnp.minimum(width, vocab - n_child)  # in-range fills among C
+    a = jnp.sum((keys >= neg).astype(jnp.int32), axis=1, keepdims=True)
+    fill_i = iota_c - a
 
-    # compare-broadcast scatter of the rank-< width winners into the C lanes
-    sel = rank[:, None, :] == iota_c[:, :, None]  # (beam_tile, width, J)
-    out_sc = jnp.sum(sel.astype(jnp.float32) * keys[:, None, :], axis=2)
-    out_tok = jnp.sum(sel.astype(toks.dtype) * toks[:, None, :], axis=2)
-    out_next = jnp.sum(sel.astype(nxts.dtype) * nxts[:, None, :], axis=2)
+    def count(j, cnt):
+        below = (_lane(cols, j) - j) <= fill_i
+        return cnt + (below & (j < n_child)).astype(jnp.int32)
 
-    out_sc_ref[...] = out_sc.astype(out_sc_ref.dtype)
-    out_tok_ref[...] = out_tok.astype(jnp.int32)
-    out_next_ref[...] = out_next.astype(jnp.int32)
+    cnt = jax.lax.fori_loop(0, n_slots, count,
+                            jnp.zeros(out_sc_ref.shape, jnp.int32))
+    is_fill = (fill_i >= 0) & (fill_i < n_fill)
+    out0 = (jnp.where(is_fill, neg, 0.0).astype(jnp.float32),
+            jnp.where(is_fill, fill_i + cnt, 0),
+            jnp.zeros(out_sc_ref.shape, jnp.int32))
+
+    # children: rank-by-counting, then a compare-broadcast scatter into C
+    def place(j, outs):
+        sc, tok, nxt = outs
+        key_j = _lane(keys, j)
+        beats = (keys > key_j) | ((keys == key_j) & (iota_s < j))
+        rank = jnp.sum(beats.astype(jnp.int32), axis=1, keepdims=True)
+        rank = rank + jnp.where(neg > key_j, n_fill, 0)
+        sel = (iota_c == rank) & (j < n_child)
+        return (jnp.where(sel, key_j, sc),
+                jnp.where(sel, _lane(cols, j), tok),
+                jnp.where(sel, _lane(nexts, j), nxt))
+
+    sc, tok, nxt = jax.lax.fori_loop(0, n_slots, place, out0)
+    out_sc_ref[...] = sc.astype(out_sc_ref.dtype)
+    out_tok_ref[...] = tok
+    out_next_ref[...] = nxt
 
 
-def _vntk_topk_body(
-    nodes_ref,
-    logits_ref,
-    rowptr_hbm,
-    edges_hbm,
-    out_sc_ref,
-    out_tok_ref,
-    out_next_ref,
-    rp_scratch,
-    edge_scratch,
-    sem_rp,
-    sem_edge,
-    *,
-    bmax_padded: int,
-    slot_chunk: int,
-    vocab: int,
-    beam_tile: int,
-    width: int,
-    fused_logsoftmax: bool,
-):
-    _dma_front(
-        nodes_ref, rowptr_hbm, edges_hbm, rp_scratch, edge_scratch,
-        sem_rp, sem_edge, beam_tile=beam_tile, bmax_padded=bmax_padded,
-    )
-    _project_and_select(
-        *_raw_slots(rp_scratch, edge_scratch), logits_ref, out_sc_ref,
-        out_tok_ref, out_next_ref, bmax_padded=bmax_padded,
-        slot_chunk=slot_chunk, vocab=vocab, beam_tile=beam_tile, width=width,
-        fused_logsoftmax=fused_logsoftmax,
-    )
+def _kernel_call(values, n_child, cols, nexts, *, vocab: int,
+                 width: int | None, fused_logsoftmax: bool, out_dtype,
+                 beam_tile: int = 8, interpret: bool | None = None):
+    """Run the shared kernel over decoded slot arrays.
 
-
-def _vntk_stacked_topk_body(
-    nodes_ref,
-    cids_ref,
-    logits_ref,
-    rowptr_hbm,
-    edges_hbm,
-    out_sc_ref,
-    out_tok_ref,
-    out_next_ref,
-    rp_scratch,
-    edge_scratch,
-    sem_rp,
-    sem_edge,
-    *,
-    bmax_padded: int,
-    slot_chunk: int,
-    vocab: int,
-    beam_tile: int,
-    width: int,
-    fused_logsoftmax: bool,
-):
-    _dma_front(
-        nodes_ref, rowptr_hbm, edges_hbm, rp_scratch, edge_scratch,
-        sem_rp, sem_edge, beam_tile=beam_tile, bmax_padded=bmax_padded,
-        cids_ref=cids_ref,
-    )
-    _project_and_select(
-        *_raw_slots(rp_scratch, edge_scratch), logits_ref, out_sc_ref,
-        out_tok_ref, out_next_ref, bmax_padded=bmax_padded,
-        slot_chunk=slot_chunk, vocab=vocab, beam_tile=beam_tile, width=width,
-        fused_logsoftmax=fused_logsoftmax,
-    )
-
-
-def _vntk_body(
-    nodes_ref,
-    logits_ref,
-    rowptr_hbm,
-    edges_hbm,
-    out_lp_ref,
-    out_next_ref,
-    rp_scratch,
-    edge_scratch,
-    sem_rp,
-    sem_edge,
-    *,
-    bmax_padded: int,
-    slot_chunk: int,
-    vocab: int,
-    beam_tile: int,
-    fused_logsoftmax: bool,
-):
-    _dma_front(
-        nodes_ref, rowptr_hbm, edges_hbm, rp_scratch, edge_scratch,
-        sem_rp, sem_edge, beam_tile=beam_tile, bmax_padded=bmax_padded,
-    )
-    _project_and_write(
-        *_raw_slots(rp_scratch, edge_scratch), logits_ref, out_lp_ref,
-        out_next_ref, bmax_padded=bmax_padded, slot_chunk=slot_chunk,
-        vocab=vocab, beam_tile=beam_tile, fused_logsoftmax=fused_logsoftmax,
-    )
-
-
-def _vntk_stacked_body(
-    nodes_ref,
-    cids_ref,
-    logits_ref,
-    rowptr_hbm,
-    edges_hbm,
-    out_lp_ref,
-    out_next_ref,
-    rp_scratch,
-    edge_scratch,
-    sem_rp,
-    sem_edge,
-    *,
-    bmax_padded: int,
-    slot_chunk: int,
-    vocab: int,
-    beam_tile: int,
-    fused_logsoftmax: bool,
-):
-    """Multi-constraint front end (DESIGN.md §4): the row-pointer and edge
-    DMAs index one extra leading constraint axis — ``rowptr (K, S+1)`` and
-    ``edges (K, E, 2)`` — by each beam's constraint id.  Everything after the
-    fetch is the shared single-matrix projection.  The DMA front is pipelined
-    exactly like :func:`_vntk_body`: every rowptr copy is in flight before
-    the first edge burst is issued."""
-    _dma_front(
-        nodes_ref, rowptr_hbm, edges_hbm, rp_scratch, edge_scratch,
-        sem_rp, sem_edge, beam_tile=beam_tile, bmax_padded=bmax_padded,
-        cids_ref=cids_ref,
-    )
-    _project_and_write(
-        *_raw_slots(rp_scratch, edge_scratch), logits_ref, out_lp_ref,
-        out_next_ref, bmax_padded=bmax_padded, slot_chunk=slot_chunk,
-        vocab=vocab, beam_tile=beam_tile, fused_logsoftmax=fused_logsoftmax,
-    )
-
-
-def _vntk_compressed_body(
-    nodes_ref,
-    base_ref,
-    logits_ref,
-    rowptr_hbm,
-    tok_hbm,
-    out_lp_ref,
-    out_next_ref,
-    rp_scratch,
-    tok_scratch,
-    sem_rp,
-    sem_edge,
-    *,
-    bmax_padded: int,
-    slot_chunk: int,
-    vocab: int,
-    beam_tile: int,
-    fused_logsoftmax: bool,
-):
-    """Compressed-slab front end (DESIGN.md §11): the edge wave DMAs the
-    delta token burst (2 B/slot at int16) and decompression is fused right
-    behind the wait — cumsum for columns, ``row_start + slot + base`` for
-    next states — before the shared projection."""
-    _dma_front(
-        nodes_ref, rowptr_hbm, tok_hbm, rp_scratch, tok_scratch,
-        sem_rp, sem_edge, beam_tile=beam_tile, bmax_padded=bmax_padded,
-    )
-    _project_and_write(
-        *_decode_delta_slots(rp_scratch, tok_scratch, base_ref), logits_ref,
-        out_lp_ref, out_next_ref, bmax_padded=bmax_padded,
-        slot_chunk=slot_chunk, vocab=vocab, beam_tile=beam_tile,
-        fused_logsoftmax=fused_logsoftmax,
-    )
-
-
-def _vntk_stacked_compressed_body(
-    nodes_ref,
-    cids_ref,
-    base_ref,
-    logits_ref,
-    rowptr_hbm,
-    tok_hbm,
-    out_lp_ref,
-    out_next_ref,
-    rp_scratch,
-    tok_scratch,
-    sem_rp,
-    sem_edge,
-    *,
-    bmax_padded: int,
-    slot_chunk: int,
-    vocab: int,
-    beam_tile: int,
-    fused_logsoftmax: bool,
-):
-    _dma_front(
-        nodes_ref, rowptr_hbm, tok_hbm, rp_scratch, tok_scratch,
-        sem_rp, sem_edge, beam_tile=beam_tile, bmax_padded=bmax_padded,
-        cids_ref=cids_ref,
-    )
-    _project_and_write(
-        *_decode_delta_slots(rp_scratch, tok_scratch, base_ref), logits_ref,
-        out_lp_ref, out_next_ref, bmax_padded=bmax_padded,
-        slot_chunk=slot_chunk, vocab=vocab, beam_tile=beam_tile,
-        fused_logsoftmax=fused_logsoftmax,
-    )
-
-
-def _vntk_compressed_topk_body(
-    nodes_ref,
-    base_ref,
-    logits_ref,
-    rowptr_hbm,
-    tok_hbm,
-    out_sc_ref,
-    out_tok_ref,
-    out_next_ref,
-    rp_scratch,
-    tok_scratch,
-    sem_rp,
-    sem_edge,
-    *,
-    bmax_padded: int,
-    slot_chunk: int,
-    vocab: int,
-    beam_tile: int,
-    width: int,
-    fused_logsoftmax: bool,
-):
-    _dma_front(
-        nodes_ref, rowptr_hbm, tok_hbm, rp_scratch, tok_scratch,
-        sem_rp, sem_edge, beam_tile=beam_tile, bmax_padded=bmax_padded,
-    )
-    _project_and_select(
-        *_decode_delta_slots(rp_scratch, tok_scratch, base_ref), logits_ref,
-        out_sc_ref, out_tok_ref, out_next_ref, bmax_padded=bmax_padded,
-        slot_chunk=slot_chunk, vocab=vocab, beam_tile=beam_tile, width=width,
-        fused_logsoftmax=fused_logsoftmax,
-    )
-
-
-def _vntk_stacked_compressed_topk_body(
-    nodes_ref,
-    cids_ref,
-    base_ref,
-    logits_ref,
-    rowptr_hbm,
-    tok_hbm,
-    out_sc_ref,
-    out_tok_ref,
-    out_next_ref,
-    rp_scratch,
-    tok_scratch,
-    sem_rp,
-    sem_edge,
-    *,
-    bmax_padded: int,
-    slot_chunk: int,
-    vocab: int,
-    beam_tile: int,
-    width: int,
-    fused_logsoftmax: bool,
-):
-    _dma_front(
-        nodes_ref, rowptr_hbm, tok_hbm, rp_scratch, tok_scratch,
-        sem_rp, sem_edge, beam_tile=beam_tile, bmax_padded=bmax_padded,
-        cids_ref=cids_ref,
-    )
-    _project_and_select(
-        *_decode_delta_slots(rp_scratch, tok_scratch, base_ref), logits_ref,
-        out_sc_ref, out_tok_ref, out_next_ref, bmax_padded=bmax_padded,
-        slot_chunk=slot_chunk, vocab=vocab, beam_tile=beam_tile, width=width,
-        fused_logsoftmax=fused_logsoftmax,
-    )
-
-
-def _vntk_call(
-    logits: jax.Array,  # (nb, V)
-    nodes: jax.Array,  # (nb,)
-    row_pointers: jax.Array,  # (S+1,)
-    edges: jax.Array,  # (E+pad, 2) stacked
-    bmax: int,
-    vocab: int,
-    *,
-    fused_logsoftmax: bool,
-    beam_tile: int = 8,
-    slot_chunk: int = 8,
-    interpret: bool | None = None,
-    out_dtype=jnp.float32,
-):
-    nb = nodes.shape[0]
-    beam_tile, nb_pad = _beam_padding(nb, beam_tile)
-    logits = _pad_rows(logits, nb_pad)
-    nodes = _pad_rows(nodes, nb_pad)  # pad rows decode from SINK (node 0)
-    bmax_padded = _round_up(max(bmax, 1), slot_chunk)
-    if edges.shape[0] < bmax_padded:
-        raise ValueError("edges tensor smaller than one speculative burst")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    grid = (nb_pad // beam_tile,)
-    kern = functools.partial(
-        _vntk_body,
-        bmax_padded=bmax_padded,
-        slot_chunk=slot_chunk,
-        vocab=vocab,
-        beam_tile=beam_tile,
-        fused_logsoftmax=fused_logsoftmax,
-    )
-    out_lp, out_next = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((beam_tile,), lambda i: (i,)),
-            pl.BlockSpec((beam_tile, vocab), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec((beam_tile, vocab), lambda i: (i, 0)),
-            pl.BlockSpec((beam_tile, vocab), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb_pad, vocab), out_dtype),
-            jax.ShapeDtypeStruct((nb_pad, vocab), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((beam_tile, 2), jnp.int32),
-            pltpu.VMEM((beam_tile, bmax_padded, 2), jnp.int32),
-            pltpu.SemaphoreType.DMA((beam_tile,)),  # per-beam rowptr sems
-            pltpu.SemaphoreType.DMA,
-        ],
-        interpret=interpret,
-    )(nodes, logits, row_pointers, edges)
-    return out_lp[:nb], out_next[:nb]
-
-
-def _vntk_stacked_call(
-    logits: jax.Array,  # (nb, V)
-    nodes: jax.Array,  # (nb,)
-    cids: jax.Array,  # (nb,)
-    row_pointers: jax.Array,  # (K, S+1)
-    edges: jax.Array,  # (K, E, 2) stacked per constraint set
-    bmax: int,
-    vocab: int,
-    *,
-    fused_logsoftmax: bool,
-    beam_tile: int = 8,
-    slot_chunk: int = 8,
-    interpret: bool | None = None,
-    out_dtype=jnp.float32,
-):
-    nb = nodes.shape[0]
-    beam_tile, nb_pad = _beam_padding(nb, beam_tile)
-    logits = _pad_rows(logits, nb_pad)
-    nodes = _pad_rows(nodes, nb_pad)  # pad rows decode from SINK (node 0)
-    cids = _pad_rows(cids, nb_pad)
-    bmax_padded = _round_up(max(bmax, 1), slot_chunk)
-    if edges.shape[1] < bmax_padded:
-        raise ValueError("edges tensor smaller than one speculative burst")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    grid = (nb_pad // beam_tile,)
-    kern = functools.partial(
-        _vntk_stacked_body,
-        bmax_padded=bmax_padded,
-        slot_chunk=slot_chunk,
-        vocab=vocab,
-        beam_tile=beam_tile,
-        fused_logsoftmax=fused_logsoftmax,
-    )
-    out_lp, out_next = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((beam_tile,), lambda i: (i,)),
-            pl.BlockSpec((beam_tile,), lambda i: (i,)),
-            pl.BlockSpec((beam_tile, vocab), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec((beam_tile, vocab), lambda i: (i, 0)),
-            pl.BlockSpec((beam_tile, vocab), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb_pad, vocab), out_dtype),
-            jax.ShapeDtypeStruct((nb_pad, vocab), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((beam_tile, 2), jnp.int32),
-            pltpu.VMEM((beam_tile, bmax_padded, 2), jnp.int32),
-            pltpu.SemaphoreType.DMA((beam_tile,)),  # per-beam rowptr sems
-            pltpu.SemaphoreType.DMA,
-        ],
-        interpret=interpret,
-    )(nodes, cids, logits, row_pointers, edges)
-    return out_lp[:nb], out_next[:nb]
-
-
-def _vntk_topk_call(
-    logits: jax.Array,  # (nb, V)
-    nodes: jax.Array,  # (nb,)
-    cids: jax.Array | None,  # (nb,) or None for the single-matrix path
-    row_pointers: jax.Array,  # (S+1,) or (K, S+1)
-    edges: jax.Array,  # (E+pad, 2) or (K, E, 2)
-    bmax: int,
-    vocab: int,
-    width: int,
-    *,
-    fused_logsoftmax: bool,
-    beam_tile: int = 8,
-    slot_chunk: int = 8,
-    interpret: bool | None = None,
-):
-    """Shared driver for the candidate-compressed kernels: three ``(nb, C)``
-    outputs instead of two ``(nb, V)`` ones."""
-    nb = nodes.shape[0]
-    beam_tile, nb_pad = _beam_padding(nb, beam_tile)
-    logits = _pad_rows(logits, nb_pad)
-    nodes = _pad_rows(nodes, nb_pad)  # pad rows decode from SINK (node 0)
-    stacked = cids is not None
-    if stacked:
-        cids = _pad_rows(cids, nb_pad)
-    bmax_padded = _round_up(max(bmax, 1), slot_chunk)
-    if edges.shape[-2] < bmax_padded:
-        raise ValueError("edges tensor smaller than one speculative burst")
-    if not 1 <= width <= vocab:
-        raise ValueError(f"width must be in [1, {vocab}], got {width}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    grid = (nb_pad // beam_tile,)
-    kern = functools.partial(
-        _vntk_stacked_topk_body if stacked else _vntk_topk_body,
-        bmax_padded=bmax_padded,
-        slot_chunk=slot_chunk,
-        vocab=vocab,
-        beam_tile=beam_tile,
-        width=width,
-        fused_logsoftmax=fused_logsoftmax,
-    )
-    row_specs = [pl.BlockSpec((beam_tile,), lambda i: (i,))]
-    if stacked:
-        row_specs.append(pl.BlockSpec((beam_tile,), lambda i: (i,)))
-    out_sc, out_tok, out_next = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=row_specs + [
-            pl.BlockSpec((beam_tile, vocab), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec((beam_tile, width), lambda i: (i, 0)),
-            pl.BlockSpec((beam_tile, width), lambda i: (i, 0)),
-            pl.BlockSpec((beam_tile, width), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb_pad, width), jnp.float32),
-            jax.ShapeDtypeStruct((nb_pad, width), jnp.int32),
-            jax.ShapeDtypeStruct((nb_pad, width), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((beam_tile, 2), jnp.int32),
-            pltpu.VMEM((beam_tile, bmax_padded, 2), jnp.int32),
-            pltpu.SemaphoreType.DMA((beam_tile,)),  # per-beam rowptr sems
-            pltpu.SemaphoreType.DMA,
-        ],
-        interpret=interpret,
-    )(*((nodes, cids) if stacked else (nodes,)), logits, row_pointers, edges)
-    return out_sc[:nb], out_tok[:nb], out_next[:nb]
-
-
-def _vntk_compressed_call(
-    logits: jax.Array,  # (nb, V)
-    nodes: jax.Array,  # (nb,)
-    cids: jax.Array | None,  # (nb,) or None for the single-matrix path
-    base: jax.Array,  # (nb,) int32 per-beam next-state base for this step
-    row_pointers: jax.Array,  # (S+1,) or (K, S+1)
-    tok_delta: jax.Array,  # (E+pad,) or (K, E+pad) int16/int32
-    bmax: int,
-    vocab: int,
-    width: int | None,
-    *,
-    fused_logsoftmax: bool,
-    beam_tile: int = 8,
-    slot_chunk: int = 8,
-    interpret: bool | None = None,
-    out_dtype=jnp.float32,
-):
-    """Shared driver for the compressed-slab kernels (DESIGN.md §11).
-
-    ``width=None`` runs the vocab-projection body (two ``(nb, V)`` outputs);
-    an integer runs the candidate-compressed selection (three ``(nb, width)``
-    outputs).  The edge scratch is the slab's own dtype — int16 where the
-    vocab permits — which is the whole HBM-bytes win."""
-    nb = nodes.shape[0]
-    beam_tile, nb_pad = _beam_padding(nb, beam_tile)
-    logits = _pad_rows(logits, nb_pad)
-    nodes = _pad_rows(nodes, nb_pad)  # pad rows decode from SINK (node 0)
-    base = _pad_rows(base, nb_pad)
-    stacked = cids is not None
-    if stacked:
-        cids = _pad_rows(cids, nb_pad)
-    bmax_padded = _round_up(max(bmax, 1), slot_chunk)
-    if tok_delta.shape[-1] < bmax_padded:
-        raise ValueError("token slab smaller than one speculative burst")
+    ``width=None`` runs the vocab projection (two ``(nb, V)`` outputs); an
+    integer runs the candidate-compressed selection (three ``(nb, width)``
+    outputs)."""
+    nb, n_slots = cols.shape
     if width is not None and not 1 <= width <= vocab:
         raise ValueError(f"width must be in [1, {vocab}], got {width}")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    grid = (nb_pad // beam_tile,)
-    topk = width is not None
-    bodies = {
-        (False, False): _vntk_compressed_body,
-        (True, False): _vntk_stacked_compressed_body,
-        (False, True): _vntk_compressed_topk_body,
-        (True, True): _vntk_stacked_compressed_topk_body,
-    }
-    static = dict(
-        bmax_padded=bmax_padded, slot_chunk=slot_chunk, vocab=vocab,
-        beam_tile=beam_tile, fused_logsoftmax=fused_logsoftmax,
-    )
-    if topk:
-        static["width"] = width
-    kern = functools.partial(bodies[(stacked, topk)], **static)
-    row_spec = pl.BlockSpec((beam_tile,), lambda i: (i,))
-    in_specs = [row_spec] + ([row_spec] if stacked else []) + [
-        row_spec,  # base
-        pl.BlockSpec((beam_tile, vocab), lambda i: (i, 0)),
-        pl.BlockSpec(memory_space=pl.ANY),
-        pl.BlockSpec(memory_space=pl.ANY),
-    ]
-    if topk:
-        out_specs = [pl.BlockSpec((beam_tile, width), lambda i: (i, 0))] * 3
-        out_shape = [
-            jax.ShapeDtypeStruct((nb_pad, width), jnp.float32),
-            jax.ShapeDtypeStruct((nb_pad, width), jnp.int32),
-            jax.ShapeDtypeStruct((nb_pad, width), jnp.int32),
-        ]
+    beam_tile, nb_pad = _beam_padding(nb, beam_tile)
+    lanes = _round_up(n_slots, LANES)
+
+    def rows(a, cols_to=None):
+        a = _pad_rows(a.astype(jnp.int32), nb_pad)  # pad rows: no children
+        if cols_to is not None and a.shape[1] < cols_to:
+            a = jnp.pad(a, [(0, 0), (0, cols_to - a.shape[1])])
+        return a
+
+    def spec(w):
+        return pl.BlockSpec((beam_tile, w), lambda i: (i, 0))
+
+    inputs = (rows(n_child[:, None]), rows(cols, lanes), rows(nexts, lanes),
+              _pad_rows(values, nb_pad))
+    in_specs = [spec(1), spec(lanes), spec(lanes), spec(vocab)]
+    if width is None:
+        body = functools.partial(_mask_body, n_slots=n_slots,
+                                 fused_logsoftmax=fused_logsoftmax)
+        outs = [(vocab, out_dtype), (vocab, jnp.int32)]
     else:
-        out_specs = [pl.BlockSpec((beam_tile, vocab), lambda i: (i, 0))] * 2
-        out_shape = [
-            jax.ShapeDtypeStruct((nb_pad, vocab), out_dtype),
-            jax.ShapeDtypeStruct((nb_pad, vocab), jnp.int32),
-        ]
-    outs = pl.pallas_call(
-        kern,
-        grid=grid,
+        body = functools.partial(_select_body, n_slots=n_slots, vocab=vocab,
+                                 fused_logsoftmax=fused_logsoftmax)
+        outs = [(width, jnp.float32), (width, jnp.int32), (width, jnp.int32)]
+    res = pl.pallas_call(
+        body,
+        grid=(nb_pad // beam_tile,),
         in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((beam_tile, 2), jnp.int32),
-            pltpu.VMEM((beam_tile, bmax_padded), tok_delta.dtype),
-            pltpu.SemaphoreType.DMA((beam_tile,)),  # per-beam rowptr sems
-            pltpu.SemaphoreType.DMA,
-        ],
+        out_specs=[spec(w) for w, _ in outs],
+        out_shape=[jax.ShapeDtypeStruct((nb_pad, w), dt) for w, dt in outs],
         interpret=interpret,
-    )(*((nodes, cids) if stacked else (nodes,)), base, logits,
-      row_pointers, tok_delta)
-    return tuple(o[:nb] for o in outs)
+    )(*inputs)
+    return tuple(o[:nb] for o in res)
+
+
+def _flat_ids(constraint_ids, batch_shape):
+    return jnp.broadcast_to(constraint_ids, batch_shape).reshape(-1).astype(
+        jnp.int32)
+
+
+def _edges_call(values, nodes, cids, row_pointers, edges, bmax, vocab, width,
+                *, fused_logsoftmax, out_dtype=jnp.float32, **kw):
+    """Kernel over the raw ``(E, 2)`` (or stacked ``(K, E, 2)``) slab."""
+    batch_shape = nodes.shape
+    bmax = max(bmax, 1)
+    if edges.shape[-2] < bmax:
+        raise ValueError("edges tensor smaller than one speculative burst")
+    n_child, idx = _burst(nodes.reshape(-1), cids, row_pointers, bmax)
+    cols, nexts = _raw_slots(edges, cids, idx)
+    outs = _kernel_call(values.reshape(-1, vocab), n_child, cols, nexts,
+                        vocab=vocab, width=width,
+                        fused_logsoftmax=fused_logsoftmax,
+                        out_dtype=out_dtype, **kw)
+    last = vocab if width is None else width
+    return tuple(o.reshape(batch_shape + (last,)) for o in outs)
+
+
+def _compressed_call(values, nodes, cids, base, row_pointers, tok_delta, bmax,
+                     vocab, width, *, fused_logsoftmax, out_dtype=jnp.float32,
+                     **kw):
+    """Kernel over the compressed slab (DESIGN.md §11); ``base`` is one
+    int32 per row."""
+    batch_shape = nodes.shape
+    bmax = max(bmax, 1)
+    if tok_delta.shape[-1] < bmax:
+        raise ValueError("token slab smaller than one speculative burst")
+    n_child, idx = _burst(nodes.reshape(-1), cids, row_pointers, bmax)
+    cols, nexts = _delta_slots(tok_delta, cids, idx, base)
+    outs = _kernel_call(values.reshape(-1, vocab), n_child, cols, nexts,
+                        vocab=vocab, width=width,
+                        fused_logsoftmax=fused_logsoftmax,
+                        out_dtype=out_dtype, **kw)
+    last = vocab if width is None else width
+    return tuple(o.reshape(batch_shape + (last,)) for o in outs)
 
 
 def vntk_pallas(
@@ -922,19 +346,9 @@ def vntk_pallas(
     **kw,
 ) -> tuple[jax.Array, jax.Array]:
     """Alg. 2 on pre-normalized log-probs. Shapes: (..., V) / (...,)."""
-    batch_shape = nodes.shape
-    lp, nxt = _vntk_call(
-        log_probs.reshape(-1, vocab),
-        nodes.reshape(-1),
-        row_pointers,
-        edges,
-        bmax,
-        vocab,
-        fused_logsoftmax=False,
-        out_dtype=log_probs.dtype,
-        **kw,
-    )
-    return lp.reshape(batch_shape + (vocab,)), nxt.reshape(batch_shape + (vocab,))
+    return _edges_call(log_probs, nodes, None, row_pointers, edges, bmax,
+                       vocab, None, fused_logsoftmax=False,
+                       out_dtype=log_probs.dtype, **kw)
 
 
 def vntk_fused_logsoftmax_pallas(
@@ -947,19 +361,8 @@ def vntk_fused_logsoftmax_pallas(
     **kw,
 ) -> tuple[jax.Array, jax.Array]:
     """Fused LogSoftmax + Alg. 2 masking in a single HBM pass."""
-    batch_shape = nodes.shape
-    lp, nxt = _vntk_call(
-        logits.reshape(-1, vocab),
-        nodes.reshape(-1),
-        row_pointers,
-        edges,
-        bmax,
-        vocab,
-        fused_logsoftmax=True,
-        out_dtype=jnp.float32,
-        **kw,
-    )
-    return lp.reshape(batch_shape + (vocab,)), nxt.reshape(batch_shape + (vocab,))
+    return _edges_call(logits, nodes, None, row_pointers, edges, bmax, vocab,
+                       None, fused_logsoftmax=True, **kw)
 
 
 def vntk_stacked_pallas(
@@ -973,21 +376,10 @@ def vntk_stacked_pallas(
     **kw,
 ) -> tuple[jax.Array, jax.Array]:
     """Alg. 2 over a stacked constraint store, pre-normalized log-probs."""
-    batch_shape = nodes.shape
-    cids = jnp.broadcast_to(constraint_ids, batch_shape).reshape(-1)
-    lp, nxt = _vntk_stacked_call(
-        log_probs.reshape(-1, vocab),
-        nodes.reshape(-1),
-        cids.astype(jnp.int32),
-        row_pointers,
-        edges,
-        bmax,
-        vocab,
-        fused_logsoftmax=False,
-        out_dtype=log_probs.dtype,
-        **kw,
-    )
-    return lp.reshape(batch_shape + (vocab,)), nxt.reshape(batch_shape + (vocab,))
+    return _edges_call(log_probs, nodes,
+                       _flat_ids(constraint_ids, nodes.shape), row_pointers,
+                       edges, bmax, vocab, None, fused_logsoftmax=False,
+                       out_dtype=log_probs.dtype, **kw)
 
 
 def vntk_stacked_fused_logsoftmax_pallas(
@@ -1001,21 +393,9 @@ def vntk_stacked_fused_logsoftmax_pallas(
     **kw,
 ) -> tuple[jax.Array, jax.Array]:
     """Fused LogSoftmax + stacked Alg. 2 masking in a single HBM pass."""
-    batch_shape = nodes.shape
-    cids = jnp.broadcast_to(constraint_ids, batch_shape).reshape(-1)
-    lp, nxt = _vntk_stacked_call(
-        logits.reshape(-1, vocab),
-        nodes.reshape(-1),
-        cids.astype(jnp.int32),
-        row_pointers,
-        edges,
-        bmax,
-        vocab,
-        fused_logsoftmax=True,
-        out_dtype=jnp.float32,
-        **kw,
-    )
-    return lp.reshape(batch_shape + (vocab,)), nxt.reshape(batch_shape + (vocab,))
+    return _edges_call(logits, nodes, _flat_ids(constraint_ids, nodes.shape),
+                       row_pointers, edges, bmax, vocab, None,
+                       fused_logsoftmax=True, **kw)
 
 
 def vntk_topk_pallas(
@@ -1034,21 +414,32 @@ def vntk_topk_pallas(
     selected in VMEM.  Returns ``(scores, tokens, next_states)``, each
     ``(..., width)``; with ``fused_logsoftmax`` the inputs are raw logits and
     normalization happens in-register before selection."""
-    batch_shape = nodes.shape
-    sc, tok, nxt = _vntk_topk_call(
-        values.reshape(-1, vocab),
-        nodes.reshape(-1),
-        None,
-        row_pointers,
-        edges,
-        bmax,
-        vocab,
-        width,
-        fused_logsoftmax=fused_logsoftmax,
-        **kw,
-    )
-    shp = batch_shape + (width,)
-    return sc.reshape(shp), tok.reshape(shp), nxt.reshape(shp)
+    return _edges_call(values, nodes, None, row_pointers, edges, bmax, vocab,
+                       width, fused_logsoftmax=fused_logsoftmax, **kw)
+
+
+def vntk_stacked_topk_pallas(
+    values: jax.Array,  # (..., V) log-probs, or raw logits when fused
+    nodes: jax.Array,
+    constraint_ids: jax.Array,
+    row_pointers: jax.Array,  # (K, S+1)
+    edges: jax.Array,  # (K, E, 2)
+    bmax: int,
+    vocab: int,
+    width: int,
+    *,
+    fused_logsoftmax: bool = False,
+    **kw,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Stacked-store candidate-compressed Alg. 2 over a ConstraintStore."""
+    return _edges_call(values, nodes, _flat_ids(constraint_ids, nodes.shape),
+                       row_pointers, edges, bmax, vocab, width,
+                       fused_logsoftmax=fused_logsoftmax, **kw)
+
+
+def _row_base(base, batch_shape):
+    return jnp.broadcast_to(jnp.asarray(base, jnp.int32),
+                            batch_shape).reshape(-1)
 
 
 def vntk_compressed_pallas(
@@ -1064,28 +455,13 @@ def vntk_compressed_pallas(
     **kw,
 ) -> tuple[jax.Array, jax.Array]:
     """Alg. 2 over the compressed slab (DESIGN.md §11): the speculative burst
-    DMAs delta tokens (int16 where the vocab permits) and decompression is
-    fused behind the wave.  Bit-identical to :func:`vntk_pallas` /
+    gathers delta tokens (int16 where the vocab permits) and decompresses
+    them in front of the kernel.  Bit-identical to :func:`vntk_pallas` /
     :func:`vntk_fused_logsoftmax_pallas` on the same trie."""
-    batch_shape = nodes.shape
-    base_b = jnp.broadcast_to(
-        jnp.asarray(base, jnp.int32), batch_shape
-    ).reshape(-1)
-    lp, nxt = _vntk_compressed_call(
-        values.reshape(-1, vocab),
-        nodes.reshape(-1),
-        None,
-        base_b,
-        row_pointers,
-        tok_delta,
-        bmax,
-        vocab,
-        None,
-        fused_logsoftmax=fused_logsoftmax,
-        out_dtype=jnp.float32 if fused_logsoftmax else values.dtype,
-        **kw,
-    )
-    return lp.reshape(batch_shape + (vocab,)), nxt.reshape(batch_shape + (vocab,))
+    return _compressed_call(
+        values, nodes, None, _row_base(base, nodes.shape), row_pointers,
+        tok_delta, bmax, vocab, None, fused_logsoftmax=fused_logsoftmax,
+        out_dtype=jnp.float32 if fused_logsoftmax else values.dtype, **kw)
 
 
 def vntk_stacked_compressed_pallas(
@@ -1102,25 +478,12 @@ def vntk_stacked_compressed_pallas(
     **kw,
 ) -> tuple[jax.Array, jax.Array]:
     """Stacked-store compressed Alg. 2: the delta burst indexes one extra
-    leading constraint axis; each beam's base is gathered host-of-kernel."""
-    batch_shape = nodes.shape
-    cids = jnp.broadcast_to(constraint_ids, batch_shape).reshape(-1)
-    cids = cids.astype(jnp.int32)
-    lp, nxt = _vntk_compressed_call(
-        values.reshape(-1, vocab),
-        nodes.reshape(-1),
-        cids,
-        base_k.astype(jnp.int32)[cids],
-        row_pointers,
-        tok_delta,
-        bmax,
-        vocab,
-        None,
-        fused_logsoftmax=fused_logsoftmax,
-        out_dtype=jnp.float32 if fused_logsoftmax else values.dtype,
-        **kw,
-    )
-    return lp.reshape(batch_shape + (vocab,)), nxt.reshape(batch_shape + (vocab,))
+    leading constraint axis; each beam's base is its member's."""
+    cids = _flat_ids(constraint_ids, nodes.shape)
+    return _compressed_call(
+        values, nodes, cids, base_k.astype(jnp.int32)[cids], row_pointers,
+        tok_delta, bmax, vocab, None, fused_logsoftmax=fused_logsoftmax,
+        out_dtype=jnp.float32 if fused_logsoftmax else values.dtype, **kw)
 
 
 def vntk_compressed_topk_pallas(
@@ -1137,27 +500,12 @@ def vntk_compressed_topk_pallas(
     **kw,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Candidate-compressed selection over the compressed slab: §8's
-    ``(nb, C)`` outputs fed by §11's 2 B/slot DMA burst — the cheapest
-    decode step in the file.  Bit-identical to :func:`vntk_topk_pallas`."""
-    batch_shape = nodes.shape
-    base_b = jnp.broadcast_to(
-        jnp.asarray(base, jnp.int32), batch_shape
-    ).reshape(-1)
-    sc, tok, nxt = _vntk_compressed_call(
-        values.reshape(-1, vocab),
-        nodes.reshape(-1),
-        None,
-        base_b,
-        row_pointers,
-        tok_delta,
-        bmax,
-        vocab,
-        width,
-        fused_logsoftmax=fused_logsoftmax,
-        **kw,
-    )
-    shp = batch_shape + (width,)
-    return sc.reshape(shp), tok.reshape(shp), nxt.reshape(shp)
+    ``(nb, C)`` outputs fed by §11's 2 B/slot burst.  Bit-identical to
+    :func:`vntk_topk_pallas`."""
+    return _compressed_call(
+        values, nodes, None, _row_base(base, nodes.shape), row_pointers,
+        tok_delta, bmax, vocab, width, fused_logsoftmax=fused_logsoftmax,
+        **kw)
 
 
 def vntk_stacked_compressed_topk_pallas(
@@ -1175,53 +523,8 @@ def vntk_stacked_compressed_topk_pallas(
     **kw,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Stacked-store compressed candidate-compressed Alg. 2."""
-    batch_shape = nodes.shape
-    cids = jnp.broadcast_to(constraint_ids, batch_shape).reshape(-1)
-    cids = cids.astype(jnp.int32)
-    sc, tok, nxt = _vntk_compressed_call(
-        values.reshape(-1, vocab),
-        nodes.reshape(-1),
-        cids,
-        base_k.astype(jnp.int32)[cids],
-        row_pointers,
-        tok_delta,
-        bmax,
-        vocab,
-        width,
-        fused_logsoftmax=fused_logsoftmax,
-        **kw,
-    )
-    shp = batch_shape + (width,)
-    return sc.reshape(shp), tok.reshape(shp), nxt.reshape(shp)
-
-
-def vntk_stacked_topk_pallas(
-    values: jax.Array,  # (..., V) log-probs, or raw logits when fused
-    nodes: jax.Array,
-    constraint_ids: jax.Array,
-    row_pointers: jax.Array,  # (K, S+1)
-    edges: jax.Array,  # (K, E, 2)
-    bmax: int,
-    vocab: int,
-    width: int,
-    *,
-    fused_logsoftmax: bool = False,
-    **kw,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Stacked-store candidate-compressed Alg. 2 over a ConstraintStore."""
-    batch_shape = nodes.shape
-    cids = jnp.broadcast_to(constraint_ids, batch_shape).reshape(-1)
-    sc, tok, nxt = _vntk_topk_call(
-        values.reshape(-1, vocab),
-        nodes.reshape(-1),
-        cids.astype(jnp.int32),
-        row_pointers,
-        edges,
-        bmax,
-        vocab,
-        width,
-        fused_logsoftmax=fused_logsoftmax,
-        **kw,
-    )
-    shp = batch_shape + (width,)
-    return sc.reshape(shp), tok.reshape(shp), nxt.reshape(shp)
+    cids = _flat_ids(constraint_ids, nodes.shape)
+    return _compressed_call(
+        values, nodes, cids, base_k.astype(jnp.int32)[cids], row_pointers,
+        tok_delta, bmax, vocab, width, fused_logsoftmax=fused_logsoftmax,
+        **kw)

@@ -10,6 +10,7 @@ import traceback  # noqa: E402
 import jax  # noqa: E402
 
 from repro.distributed.collectives import parse_collective_bytes  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.steps import build_cell, list_cells  # noqa: E402
 
@@ -87,6 +88,7 @@ def main():
     ap.add_argument("--resume", action="store_true",
                     help="skip cells already present in --out")
     args = ap.parse_args()
+    enable_compile_cache()
 
     runnable, skipped = list_cells()
     cells = [

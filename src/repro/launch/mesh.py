@@ -13,50 +13,32 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["make_mesh_compat", "set_mesh_compat", "make_production_mesh",
-           "make_debug_mesh", "make_subset_mesh", "POD_SHAPE",
-           "MULTIPOD_SHAPE"]
+__all__ = ["make_production_mesh", "make_debug_mesh", "make_subset_mesh",
+           "POD_SHAPE", "MULTIPOD_SHAPE"]
 
 POD_SHAPE = (16, 16)
 MULTIPOD_SHAPE = (2, 16, 16)
 
 
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` with ``axis_types=Auto`` when this JAX supports it.
-
-    ``jax.sharding.AxisType`` only exists from jax 0.5.x; older installs get
-    the plain call (whose axes are Auto-equivalent by default).
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(axis_type.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
-
-
-def set_mesh_compat(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` arrived after 0.4.x; on older installs ``Mesh`` itself is
-    the resource-env context manager, so return it directly.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with sharding-in-types off: every axis is ``Auto``
+    (``jax.make_mesh`` defaults to ``Explicit``)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = MULTIPOD_SHAPE if multi_pod else POD_SHAPE
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_devices: int | None = None, model: int = 2):
     """Small mesh over however many devices exist (tests / examples)."""
     n = n_devices or len(jax.devices())
     model = min(model, n)
-    return make_mesh_compat((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 def make_subset_mesh(data: int, model: int = 1):

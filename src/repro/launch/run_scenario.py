@@ -25,6 +25,7 @@ import json
 import logging
 import sys
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.scenarios import (
     config_to_dict,
     get_default_registry,
@@ -67,6 +68,7 @@ def main(argv=None) -> int:
     if args.scenario is None:
         ap.error("--scenario NAME required (or --list)")
 
+    enable_compile_cache()
     overrides = dict(parse_override(s) for s in args.overrides)
     run = registry.resolve(args.scenario, smoke=args.smoke,
                            overrides=overrides, seed=args.seed)

@@ -2,6 +2,17 @@
 
     PYTHONPATH=src python -m repro.launch.serve --constraints 20000 \
         --batch 4 --beam 8
+    PYTHONPATH=src python -m repro.launch.serve --model static-gr
+
+``--model`` picks the decoder: ``gr-coldstart`` (the reduced 4-layer
+cold-start decoder, default) or a generative-retrieval config of
+:mod:`repro.configs` by name — ``static-gr`` is the paper's 26-layer x 3072
+decoder, served at its own SID geometry (V=2048, L=8, M=70, 2 requests per
+chip, ``dense_d=2``) and history length (256).  Weights are random, made
+from ``--seed`` on the device.  The builders below (:func:`decoder`,
+:func:`build_params`, :func:`build_index`, :func:`build_policy`,
+:func:`build_retriever`, :func:`build_engine`) are what ``chip_smoke.py``
+calls too.
 
 Which engine when (``--engine``):
 
@@ -51,36 +62,170 @@ they are the ``multi_constraint`` and ``refresh_churn`` scenarios::
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import logging
 import time
 
 import jax
 import numpy as np
 
+from repro.configs import get_bundle, static_gr
+from repro.configs.base import TransformerConfig
 from repro.core import TransitionMatrix
 from repro.core.vntk import NEG_INF
 from repro.decoding import DecodePolicy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer
-from repro.observability import (
-    MetricsRegistry,
-    StepTimer,
-    start_http_server,
-)
+from repro.observability import MetricsRegistry, start_http_server
 from repro.reliability import CircuitBreaker, FaultInjector, HealthMonitor, install
 from repro.scenarios import gr_model_config
+from repro.serving.engine import RequestQueue, ServingEngine
 from repro.serving.generative_retrieval import GenerativeRetriever
 
 logger = logging.getLogger("repro.launch.serve")
 
+TOY_MODEL = "gr-coldstart"
+MODELS = (TOY_MODEL, "static-gr")
+
+
+@dataclasses.dataclass(frozen=True)
+class Geometry:
+    """The SID geometry and request shape a decoder is served at."""
+
+    vocab: int  # SID token cardinality V
+    sid_length: int  # L
+    beam: int  # M
+    batch: int  # requests per batch (per chip)
+    history: int  # prompt tokens per request
+    dense_d: int  # bit-packed dense levels of the constraint index
+    constraints: int  # constraint SIDs served by default
+
+
+def decoder(model: str = TOY_MODEL,
+            vocab: int | None = None) -> tuple[TransformerConfig, Geometry]:
+    """Decoder config and serving geometry for ``--model``."""
+    if model == TOY_MODEL:
+        v = vocab or 256
+        return gr_model_config(v), Geometry(v, 4, 8, 4, 16, 2, 20_000)
+    bundle = get_bundle(model)
+    if bundle is not static_gr.BUNDLE:
+        raise ValueError(
+            f"{model!r} is not a generative-retrieval decoder; "
+            f"choose one of {MODELS}")
+    shape = next(s for s in bundle.shapes if s.kind == "serve_constrained")
+    v = vocab or static_gr.SID_VOCAB
+    if v + 2 > bundle.config.vocab_size:
+        raise ValueError(f"SID vocab {v} exceeds the decoder's "
+                         f"{bundle.config.vocab_size - 2} SID tokens")
+    # 1M constraint SIDs: the paper's 20M slab does not fit one 16 GB chip
+    # beside the weights and batch 2's beam cache
+    return bundle.config, Geometry(
+        v, shape.sid_length, shape.beam_size, static_gr.BATCH_PER_CHIP,
+        shape.history_len, static_gr.DENSE_D, 1_000_000)
+
+
+def build_params(cfg: TransformerConfig, seed: int = 0, sharding=None):
+    """Random decoder weights from ``seed``, made on the device by one
+    jitted program (eager init would stage every stacked layer's float32
+    draw on the device before casting it).  ``sharding`` places the result
+    (e.g. replicated over a mesh)."""
+    init = jax.jit(transformer.init_params, static_argnums=0,
+                   out_shardings=sharding)
+    return init(cfg, jax.random.key(seed))
+
+
+def constraint_sids(n: int, geo: Geometry, seed: int = 0) -> np.ndarray:
+    """``n`` random constraint SIDs over the geometry's vocab and length."""
+    rng = np.random.default_rng(seed)
+    return rng.integers(0, geo.vocab, size=(n, geo.sid_length))
+
+
+def request_histories(n: int, geo: Geometry, seed: int = 0,
+                      distinct: int | None = None) -> np.ndarray:
+    """``n`` user histories of ``geo.history`` tokens; with ``distinct``
+    they cycle through that many prompts (repeat prompts exercise prefix
+    sharing in the continuous engine)."""
+    rng = np.random.default_rng(seed + 1)
+    pool = rng.integers(0, geo.vocab, (distinct or n, geo.history))
+    return pool[np.arange(n) % len(pool)].astype(np.int32)
+
+
+def build_index(sids: np.ndarray, geo: Geometry,
+                dense_d: int | None = None) -> TransitionMatrix:
+    """The STATIC constraint index (CSR trie + dense levels) over ``sids``."""
+    d = geo.dense_d if dense_d is None else dense_d
+    return TransitionMatrix.from_sids(sids, geo.vocab, dense_d=d)
+
+
+def build_policy(tm: TransitionMatrix | None, *, impl: str = "xla",
+                 fused: bool = False, topk: bool = True) -> DecodePolicy:
+    """STATIC decode policy over ``tm`` (unconstrained when ``tm`` is None)."""
+    if tm is None:
+        return DecodePolicy.unconstrained()
+    return DecodePolicy.static(tm, impl=impl, fused=fused, topk=topk)
+
+
+def build_retriever(params, cfg: TransformerConfig, policy, geo: Geometry, *,
+                    mesh=None, rows: str = "replicated"):
+    """Single-device retriever, or the SPMD one over ``mesh``."""
+    if mesh is None:
+        return GenerativeRetriever(params, cfg, policy, geo.sid_length,
+                                   geo.vocab, beam_size=geo.beam)
+    from repro.serving.spmd_engine import SpmdRetriever
+
+    return SpmdRetriever(params, cfg, policy, geo.sid_length, geo.vocab,
+                         beam_size=geo.beam, mesh=mesh, rows=rows)
+
+
+def build_engine(kind: str, retriever, geo: Geometry, *, metrics=None,
+                 breaker=None, prefill_chunk: int | None = None,
+                 share_capacity: int = 64):
+    """The serving engine ``kind`` (batch | spmd | continuous) over
+    ``retriever``, with ``geo.batch`` slots and ``geo.history``-token
+    prompts.  The continuous engine prefills up to ``prefill_chunk``
+    prompts per step (default: half the slots)."""
+    if kind == "continuous":
+        from repro.serving.continuous import ContinuousServingEngine
+
+        return ContinuousServingEngine(
+            retriever, slots=geo.batch, prompt_width=geo.history,
+            prefill_chunk=prefill_chunk or max(geo.batch // 2, 1),
+            share_capacity=share_capacity, metrics=metrics, breaker=breaker)
+    if kind == "spmd":
+        from repro.serving.spmd_engine import SpmdServingEngine
+
+        return SpmdServingEngine(retriever, slots=geo.batch,
+                                 prompt_width=geo.history, metrics=metrics,
+                                 breaker=breaker)
+    if kind != "batch":
+        raise ValueError(f"unknown engine {kind!r}")
+    return ServingEngine(retriever.params, retriever.cfg, geo.batch,
+                         2 * geo.history, retriever=retriever,
+                         metrics=metrics, breaker=breaker)
+
+
+def compliance(beams: np.ndarray, scores: np.ndarray, valid: set) -> tuple:
+    """``(beams checked, beams outside the constraint set)`` over every
+    beam that carries a finite score."""
+    live = np.asarray(scores) > NEG_INF / 2
+    emitted = [tuple(b) for b in np.asarray(beams)[live].tolist()]
+    return len(emitted), sum(b not in valid for b in emitted)
+
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--constraints", type=int, default=20_000)
-    ap.add_argument("--vocab", type=int, default=256)
-    ap.add_argument("--sid-length", type=int, default=4)
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--beam", type=int, default=8)
-    ap.add_argument("--requests", type=int, default=5)
+    ap.add_argument("--model", choices=MODELS, default=TOY_MODEL,
+                    help="decoder: the reduced cold-start decoder or the "
+                         "paper's static-gr-3b at its own SID geometry")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the weights, constraint SIDs and requests")
+    ap.add_argument("--constraints", type=int, default=None)
+    ap.add_argument("--vocab", type=int, default=None)
+    ap.add_argument("--sid-length", type=int, default=None)
+    ap.add_argument("--batch", type=int, default=None)
+    ap.add_argument("--beam", type=int, default=None)
+    ap.add_argument("--requests", type=int, default=5,
+                    help="batches' worth of requests to serve")
     ap.add_argument("--unconstrained", action="store_true")
     ap.add_argument("--impl", choices=["xla", "pallas"], default="xla",
                     help="VNTK formulation for sparse decode levels")
@@ -93,8 +238,7 @@ def main():
     ap.add_argument("--engine", choices=["batch", "spmd", "continuous"],
                     default="batch",
                     help="serving engine (see the module docstring's "
-                         "which-engine-when table); 'continuous' runs the "
-                         "step-boundary engine demo over a RequestQueue")
+                         "which-engine-when table)")
     ap.add_argument("--spmd", action="store_true",
                     help="alias for --engine spmd: serve SPMD over a (data, "
                          "model) mesh spanning every visible device "
@@ -130,6 +274,7 @@ def main():
         level=getattr(logging, args.log_level),
         format="%(asctime)s %(levelname)s %(name)s %(message)s",
     )
+    enable_compile_cache()
     metrics = MetricsRegistry()
 
     injector = None
@@ -155,100 +300,81 @@ def main():
     if args.spmd:
         args.engine = "spmd"
 
-    rng = np.random.default_rng(0)
-    cfg = gr_model_config(args.vocab)
-    params = transformer.init_params(cfg, jax.random.key(0))
-    sids = rng.integers(0, args.vocab, size=(args.constraints, args.sid_length))
+    cfg, geo = decoder(args.model, args.vocab)
+    geo = dataclasses.replace(geo, **{
+        k: v for k, v in dict(
+            sid_length=args.sid_length, batch=args.batch, beam=args.beam,
+            constraints=args.constraints).items() if v is not None})
+    logger.info("decoder %s (%d layers x %d), V=%d L=%d M=%d, batch %d, "
+                "history %d", cfg.name, cfg.n_layers, cfg.d_model, geo.vocab,
+                geo.sid_length, geo.beam, geo.batch, geo.history)
+    params = build_params(cfg, args.seed)
+    sids = constraint_sids(geo.constraints, geo, args.seed)
     tm = None
-    policy = DecodePolicy.unconstrained()
     if not args.unconstrained or args.engine == "continuous":
         t0 = time.time()
         # the continuous engine's level-free masking needs the all-sparse
         # index (node ids globally unique across levels)
-        dense_d = 0 if args.engine == "continuous" else 2
-        tm = TransitionMatrix.from_sids(sids, args.vocab, dense_d=dense_d)
-        policy = DecodePolicy.static(tm, impl=args.impl, fused=args.fused,
-                                     topk=not args.no_topk)
-        logger.info("constraint index: %d states (%.2fs build); policy %s",
-                    tm.n_states, time.time() - t0, policy.describe())
+        tm = build_index(sids, geo,
+                         dense_d=0 if args.engine == "continuous" else None)
+        logger.info("constraint index: %d states (%.2fs build)",
+                    tm.n_states, time.time() - t0)
+    policy = build_policy(tm, impl=args.impl, fused=args.fused,
+                          topk=not args.no_topk)
+    logger.info("policy %s", policy.describe())
 
-    if args.engine == "continuous":
-        from repro.serving.continuous import ContinuousServingEngine
-        from repro.serving.engine import RequestQueue
-
-        r = GenerativeRetriever(params, cfg, policy, args.sid_length,
-                                args.vocab, beam_size=args.beam)
-        engine = ContinuousServingEngine(
-            r, slots=args.batch, prompt_width=16,
-            prefill_chunk=max(args.batch // 2, 1), metrics=metrics,
-            breaker=breaker)
-        queue = RequestQueue()
-        n_req = args.requests * args.batch
-        pool = rng.integers(0, args.vocab, (max(n_req // 3, 1), 16))
-        rids = [queue.submit(pool[i % len(pool)].astype(np.int32),
-                             args.sid_length) for i in range(n_req)]
-        t0 = time.time()
-        results = engine.serve(queue)
-        done = [i for i in rids if "latency_s" in results[i]]
-        if injector is not None and len(done) < n_req:
-            logger.info("degraded under faults: %d/%d completed (%s)",
-                        len(done), n_req,
-                        {results[i].get("reason", "?")
-                         for i in rids if i not in set(done)})
-        lat = np.array([results[i]["latency_s"] for i in done]
-                       or [float("nan")])
-        hits = engine.metrics.counter("serving_prefix_share_hits_total")
-        logger.info(
-            "continuous: %d requests in %.1f ms (p50 %.1f ms, p99 %.1f ms); "
-            "slot reuse %d, share hits prompt=%d mask_row=%d",
-            len(done), (time.time() - t0) * 1e3,
-            float(np.quantile(lat, 0.5)) * 1e3,
-            float(np.quantile(lat, 0.99)) * 1e3,
-            int(engine.metrics.counter("serving_slot_reuse_total").total()),
-            int(hits.value(kind="prompt")), int(hits.value(kind="mask_row")))
-        if done:
-            top1 = results[done[0]]["sids"][0].tolist()
-            logger.info("top-1 SIDs (request %d): %s", done[0], top1)
-        if injector is not None:
-            logger.info("injected faults fired: %d", injector.n_fires())
-        if args.metrics_json:
-            metrics.write_snapshot(args.metrics_json)
-            logger.info("metrics snapshot appended to %s", args.metrics_json)
-        return
-
+    mesh = None
     if args.engine == "spmd":
         from repro.launch.mesh import make_debug_mesh
-        from repro.serving.spmd_engine import SpmdRetriever
 
         mesh = make_debug_mesh(model=2 if args.spmd_rows == "model" else 1)
         logger.info("SPMD mesh: %s over %d device(s), CSR rows=%s",
                     dict(mesh.shape), mesh.devices.size, args.spmd_rows)
-        r = SpmdRetriever(params, cfg, policy, args.sid_length, args.vocab,
-                          beam_size=args.beam, mesh=mesh, rows=args.spmd_rows)
-    else:
-        r = GenerativeRetriever(params, cfg, policy, args.sid_length,
-                                args.vocab, beam_size=args.beam)
-    hist = rng.integers(0, args.vocab, (args.batch, 16)).astype(np.int32)
-    # StepTimer: warmup absorbs compilation, trials block on all outputs,
-    # and every trial lands in the step_wall_seconds{step} histogram
-    timer = StepTimer("retrieve_batch", metrics, warmup=1,
-                      trials=args.requests)
-    stats = timer.measure(lambda: r.retrieve(hist))
-    beams, scores = r.retrieve(hist)
-    valid = {tuple(x) for x in sids}
-    compliant = all(
-        tuple(beams[b, m]) in valid
-        for b in range(args.batch) for m in range(args.beam)
-        if scores[b, m] > NEG_INF / 2
-    ) if tm is not None else "n/a"
-    logger.info(
-        "%.1f ms/request-batch of %d (beam %d, p99 %.1f ms, dispatch "
-        "%.2f ms); compliance: %s",
-        stats.median * 1e3, args.batch, args.beam, stats.p99 * 1e3,
-        stats.dispatch_median * 1e3, compliant,
-    )
-    logger.info("top-1 SIDs: %s", beams[:, 0, :].tolist())
+    r = build_retriever(params, cfg, policy, geo, mesh=mesh,
+                        rows=args.spmd_rows)
+    engine = build_engine(args.engine, r, geo, metrics=metrics,
+                          breaker=breaker)
 
+    n_req = args.requests * geo.batch
+    queue = RequestQueue()
+    hist = request_histories(n_req, geo, args.seed,
+                             distinct=max(n_req // 3, 1))
+    rids = [queue.submit(h, geo.sid_length) for h in hist]
+    t0 = time.time()
+    results = engine.serve(queue)
+    wall = time.time() - t0
+    done = [i for i in rids if "latency_s" in results[i]]
+    if injector is not None and len(done) < n_req:
+        logger.info("degraded under faults: %d/%d completed (%s)",
+                    len(done), n_req,
+                    {results[i].get("reason", "?")
+                     for i in rids if i not in set(done)})
+    lat = np.array([results[i]["latency_s"] for i in done] or [float("nan")])
+    logger.info(
+        "%s: %d requests in %.1f ms (p50 %.1f ms, p99 %.1f ms)%s",
+        args.engine, len(done), wall * 1e3,
+        float(np.quantile(lat, 0.5)) * 1e3,
+        float(np.quantile(lat, 0.99)) * 1e3,
+        "" if args.engine == "continuous"
+        else "; the first batch includes compilation")
+    if tm is not None and done:
+        valid = {tuple(x) for x in sids.tolist()}
+        checked, bad = compliance(
+            np.stack([results[i]["sids"] for i in done]),
+            np.stack([results[i]["scores"] for i in done]), valid)
+        logger.info("compliance: %d/%d beams inside the constraint set",
+                    checked - bad, checked)
+    if args.engine == "continuous":
+        hits = engine.metrics.counter("serving_prefix_share_hits_total")
+        logger.info(
+            "slot reuse %d, share hits prompt=%d mask_row=%d",
+            int(engine.metrics.counter("serving_slot_reuse_total").total()),
+            int(hits.value(kind="prompt")), int(hits.value(kind="mask_row")))
+    if done:
+        logger.info("top-1 SIDs (request %d): %s", done[0],
+                    results[done[0]]["sids"][0].tolist())
+    if injector is not None:
+        logger.info("injected faults fired: %d", injector.n_fires())
     if args.metrics_json:
         metrics.write_snapshot(args.metrics_json)
         logger.info("metrics snapshot appended to %s", args.metrics_json)

@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.configs import get_bundle, smoke_config
 from repro.data.loader import ShardedBatcher
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import gnn, recsys, transformer
 from repro.training.optimizer import adamw
 from repro.training.trainer import Trainer, TrainerConfig
@@ -62,6 +63,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
 
+    enable_compile_cache()
     fam = get_bundle(args.arch).family
     cfg = smoke_config(args.arch)
     key = jax.random.key(0)

@@ -81,9 +81,11 @@ def chunked_causal_attention(
             acc_new = acc * corr[..., None] + pv
             return (m_new, l_new, acc_new), None
 
-        m0 = jnp.full((B, H, chunk_q), NEG, jnp.float32)
-        l0 = jnp.zeros((B, H, chunk_q), jnp.float32)
-        a0 = jnp.zeros((B, H, chunk_q, Dv), jnp.float32)
+        # the carry is built from q_c so that, inside shard_map, it varies
+        # over the same mesh axes as the body's output
+        l0 = jnp.zeros_like(q_c[..., 0], jnp.float32).transpose(0, 2, 1)
+        m0 = jnp.full_like(l0, NEG)
+        a0 = jnp.broadcast_to(l0[..., None], (B, H, chunk_q, Dv))
         (m, l, acc), _ = jax.lax.scan(
             kv_body, (m0, l0, a0), (jnp.arange(nk), ks, vs),
             unroll=nk if unroll else 1,

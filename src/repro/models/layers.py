@@ -89,6 +89,12 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10_000.0):
     """x: (..., S, H, Dh) or (..., S, Dh); positions: broadcastable to (..., S)."""
     dh = x.shape[-1]
     freqs = rope_frequencies(dh, theta)  # (Dh/2,)
+    # The barrier keeps XLA from constant-folding cos/sin when the positions
+    # are static: folded values come from the compiler's host math, which
+    # differs from the TPU's in the last place, so a decode at a static
+    # position (the batch engine) would not match the same decode at a
+    # traced one (the continuous engine).
+    positions = jax.lax.optimization_barrier(positions)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, Dh/2)
     if x.ndim == angles.ndim + 1:  # head axis present
         angles = angles[..., None, :]

@@ -115,6 +115,15 @@ class GenerativeRetriever:
         )
         return np.asarray(tokens), np.asarray(scores)
 
+    def compile_step(self, batch: int, prompt_width: int):
+        """Compile the single-matrix retrieval step ahead of time for
+        ``(batch, prompt_width)`` histories; ``retrieve`` at that shape then
+        reuses it.  The result's ``memory_analysis()`` and ``as_text()``
+        size and inspect the step before it runs."""
+        hist = jax.ShapeDtypeStruct((batch, prompt_width), jnp.int32)
+        return self._retrieve_jit.lower(
+            self.params, hist, self.policy, None).compile()
+
     def _retrieve_impl(self, params, history, policy, constraint_ids):
         B, S = history.shape
         M = self.M

@@ -50,7 +50,7 @@ from repro.distributed.constraint_sharding import (
     policy_pspecs,
     to_row_sharded,
 )
-from repro.distributed.sharding import dp_axes, dp_size, shard_map_compat
+from repro.distributed.sharding import dp_axes, dp_size
 from repro.observability import (
     MetricsRegistry,
     annotate,
@@ -126,11 +126,23 @@ class SpmdRetriever(GenerativeRetriever):
             scores = jnp.where(active[:, None], scores, NEG_INF)
             return tokens, scores
 
-        self._spmd_jit = jax.jit(shard_map_compat(
+        self._spmd_jit = jax.jit(jax.shard_map(
             _spmd_impl, mesh=self.mesh,
             in_specs=(P(), P(dp, None), specs, P(dp), P(dp)),
             out_specs=(P(dp, None, None), P(dp, None)),
         ))
+
+    def compile_step(self, batch: int, prompt_width: int):
+        """Ahead-of-time compile of the mesh step for a global ``batch`` (a
+        multiple of the data-parallel ways); ``memory_analysis()`` of the
+        result is per device."""
+        def rows(dtype):
+            return jax.ShapeDtypeStruct((batch,), dtype)
+
+        hist = jax.ShapeDtypeStruct((batch, prompt_width), jnp.int32)
+        return self._spmd_jit.lower(self.params, hist, self.policy,
+                                    rows(jnp.int32), rows(jnp.bool_)
+                                    ).compile()
 
     # -- hot-swap ------------------------------------------------------------
     def set_constraints(self, obj) -> bool:

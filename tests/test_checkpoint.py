@@ -65,9 +65,8 @@ def test_elastic_restore_with_shardings(tmp_path):
 
     t = {"w": jnp.arange(16, dtype=jnp.float32).reshape(4, 4)}
     ck.save(str(tmp_path), 3, t)
-    from repro.launch.mesh import make_mesh_compat
-
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     sh = {"w": NamedSharding(mesh, P())}
     r = ck.restore(str(tmp_path), 3, jax.tree.map(jnp.zeros_like, t), sh)
     np.testing.assert_array_equal(np.asarray(r["w"]), np.asarray(t["w"]))

@@ -281,6 +281,20 @@ def test_fuzz_bit_identical_to_serving_engine(gr_stack):
         assert "latency_s" in b[rid] and "queue_s" in b[rid]
 
 
+def test_static_position_rope_is_not_constant_folded():
+    """The batch engine decodes at compile-time-constant positions, the
+    continuous engine at traced ones.  Both must compute RoPE's cos/sin on
+    the device: a folded cos/sin comes from the compiler's host math, which
+    on a TPU differs from the device's in the last place and breaks the
+    engines' bit-identity there."""
+    from repro.models.layers import apply_rope
+
+    x = jnp.ones((1, 1, 2, 8), jnp.float32)
+    txt = jax.jit(lambda x: apply_rope(x, jnp.asarray([261]))).lower(
+        x).compile().as_text()
+    assert "cosine(" in txt and "sine(" in txt
+
+
 def test_fuzz_bit_identical_across_hot_swap(gr_stack):
     churned = synthetic_catalog(np.random.default_rng(13), 300,
                                 gr_stack["vocab"], gr_stack["L"])

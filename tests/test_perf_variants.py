@@ -109,10 +109,9 @@ def test_split_k_flag_is_noop_without_mesh():
     tokens = jax.random.randint(jax.random.key(1), (B, S + 1), 0, cfg.vocab_size)
     _, cache = transformer.prefill(params, tokens[:, :S], cfg, max_len=S + 4)
     la, _ = transformer.decode_step(params, cache, tokens[:, S:S + 1], cfg)
-    from repro.launch.mesh import make_mesh_compat, set_mesh_compat
-
-    mesh = make_mesh_compat((1,), ("data",))
-    with set_mesh_compat(mesh):
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
+    with jax.set_mesh(mesh):
         cfg_s = dataclasses.replace(cfg_s, sp_axes=("data",))
         lb, _ = transformer.decode_step(params, cache, tokens[:, S:S + 1], cfg_s)
     np.testing.assert_allclose(np.asarray(la), np.asarray(lb), rtol=2e-4,

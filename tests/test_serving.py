@@ -1,4 +1,6 @@
 """Serving: engine greedy generation, continuous batching, constrained GR."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -112,3 +114,70 @@ def test_generative_retriever_unconstrained_vs_constrained_scores(small_lm, rng)
     _, s_c = g_c.retrieve(hist)
     _, s_u = g_u.retrieve(hist)
     assert (s_c[:, 0] <= s_u[:, 0] + 1e-4).all()
+
+
+# ---------------------------------------------------------------------------
+# launch.serve builders (the CLI and chip_smoke.py share them)
+# ---------------------------------------------------------------------------
+def test_static_gr_decoder_geometry():
+    from repro.launch import serve
+
+    cfg, geo = serve.decoder("static-gr")
+    assert (cfg.name, cfg.n_layers, cfg.d_model, cfg.dtype) == (
+        "static-gr-3b", 26, 3072, "bfloat16")
+    assert (geo.vocab, geo.sid_length, geo.beam, geo.batch, geo.history,
+            geo.dense_d) == (2048, 8, 70, 2, 256, 2)
+    with pytest.raises(ValueError):
+        serve.decoder("stablelm-12b")
+
+
+@pytest.mark.parametrize("kind", ["batch", "spmd", "continuous"])
+def test_serve_builders_serve_compliant_beams(kind):
+    from repro.launch import serve
+
+    cfg, geo = serve.decoder(serve.TOY_MODEL, vocab=64)
+    cfg = dataclasses.replace(cfg, n_layers=2)
+    geo = dataclasses.replace(geo, batch=2, beam=4, constraints=500)
+    params = serve.build_params(cfg, seed=1)
+    sids = serve.constraint_sids(geo.constraints, geo, seed=1)
+    tm = serve.build_index(sids, geo, dense_d=0 if kind == "continuous"
+                           else None)
+    mesh = None
+    if kind == "spmd":
+        from repro.launch.mesh import make_debug_mesh
+
+        mesh = make_debug_mesh(model=1)
+    r = serve.build_retriever(params, cfg, serve.build_policy(tm), geo,
+                              mesh=mesh)
+    engine = serve.build_engine(kind, r, geo)
+    q = RequestQueue()
+    hist = serve.request_histories(4, geo, seed=1)
+    rids = [q.submit(h, geo.sid_length) for h in hist]
+    res = engine.serve(q)
+    beams = np.stack([res[i]["sids"] for i in rids])
+    scores = np.stack([res[i]["scores"] for i in rids])
+    checked, bad = serve.compliance(beams, scores,
+                                    {tuple(s) for s in sids.tolist()})
+    assert checked == 4 * geo.beam and bad == 0
+    want = GenerativeRetriever(params, cfg, serve.build_policy(tm),
+                               geo.sid_length, geo.vocab,
+                               beam_size=geo.beam).retrieve(hist[:2])
+    np.testing.assert_array_equal(beams[:2], want[0])
+
+
+def test_compile_cache_dir_rule(monkeypatch):
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert compile_cache.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        assert path == str(compile_cache.DEFAULT_DIR)
+        assert compile_cache.DEFAULT_DIR.parent.joinpath("chip_smoke.py"
+                                                         ).exists()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -145,8 +145,6 @@ def test_spmd_beam_search_rejects_ragged_batch(corpus):
 # row-sharded CSR: one-hop gather == replicated VNTK, and padding is inert
 # ---------------------------------------------------------------------------
 def test_vntk_row_sharded_matches_replicated(corpus, rng):
-    from repro.distributed.sharding import shard_map_compat
-
     _, tm, _ = corpus
     mesh = model_mesh()
     ms = mesh.shape["model"]
@@ -159,7 +157,7 @@ def test_vntk_row_sharded_matches_replicated(corpus, rng):
     lp = jnp.asarray(rng.normal(size=(12, V)).astype(np.float32))
     want_lp, want_nx = vntk_xla(lp, nodes, tm, bmax)
 
-    f = jax.jit(shard_map_compat(
+    f = jax.jit(jax.shard_map(
         lambda lp, nodes, rp, edges: vntk_row_sharded(
             lp, nodes, rp, edges, bmax, V, "model"),
         mesh=mesh,
