@@ -174,34 +174,40 @@ def _attn_full(p, x, cfg: TransformerConfig, q_offset=0):
             y = y + pp["b"]
         return y.reshape(B, S, width, hd)
 
-    q = proj(p["wq"], H)
-    k = proj(p["wk"], KV)
-    v = proj(p["wv"], KV)
-    pos = q_offset + jnp.arange(S)
-    q = apply_rope(q, pos[None], cfg.rope_theta)
-    k = apply_rope(k, pos[None], cfg.rope_theta)
-    out = chunked_causal_attention(
-        q, k, v, chunk_q=cfg.attn_chunk_q, chunk_kv=cfg.attn_chunk_kv,
-        window=cfg.sliding_window, q_offset=q_offset,
-        unroll=cfg.inner_unroll,
-    )
-    return out.reshape(B, S, H * hd) @ p["wo"]["w"], (k, v)
+    with jax.named_scope("qkv_proj"):
+        q = proj(p["wq"], H)
+        k = proj(p["wk"], KV)
+        v = proj(p["wv"], KV)
+        pos = q_offset + jnp.arange(S)
+        q = apply_rope(q, pos[None], cfg.rope_theta)
+        k = apply_rope(k, pos[None], cfg.rope_theta)
+    with jax.named_scope("attention"):
+        out = chunked_causal_attention(
+            q, k, v, chunk_q=cfg.attn_chunk_q, chunk_kv=cfg.attn_chunk_kv,
+            window=cfg.sliding_window, q_offset=q_offset,
+            unroll=cfg.inner_unroll,
+        )
+    with jax.named_scope("out_proj"):
+        return out.reshape(B, S, H * hd) @ p["wo"]["w"], (k, v)
 
 
 def _layer_fwd(p, x, cfg: TransformerConfig, moe_layer: bool, q_offset=0):
     attn_out, cache_kv = _attn_with_norm(p, x, cfg, q_offset)
-    x = x + attn_out
-    h = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
-    if moe_layer:
-        y, aux = moe_ffn(p["moe"], h, cfg.moe)
-        x = x + y
-        return x, cache_kv, aux
-    x = x + swiglu(p["ffn"], h)
+    with jax.named_scope("out_proj"):
+        x = x + attn_out
+    with jax.named_scope("ffn"):
+        h = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
+        if moe_layer:
+            y, aux = moe_ffn(p["moe"], h, cfg.moe)
+            x = x + y
+            return x, cache_kv, aux
+        x = x + swiglu(p["ffn"], h)
     return x, cache_kv, jnp.zeros((), jnp.float32)
 
 
 def _attn_with_norm(p, x, cfg, q_offset):
-    h = rms_norm(p["ln_attn"], x, cfg.norm_eps)
+    with jax.named_scope("qkv_proj"):
+        h = rms_norm(p["ln_attn"], x, cfg.norm_eps)
     return _attn_full(p["attn"], h, cfg, q_offset)
 
 
@@ -426,60 +432,68 @@ def _decode_attn_gqa(p, x, cfg, k_cache, v_cache, slot_pos, pos, slot=None):
             y = y + pp["b"]
         return y.reshape(B, 1, width, hd)
 
-    q = proj(p["wq"], H)
-    k_new = proj(p["wk"], KV)
-    v_new = proj(p["wv"], KV)
-    q = apply_rope(q, pos[None, None], cfg.rope_theta)
-    k_new = apply_rope(k_new, pos[None, None], cfg.rope_theta)
-    if cfg.decode_split_k:
-        # replicate the tiny per-token tensors over `model`; the cache stays
-        # sequence-sharded and attention contracts shard-locally (split-K).
-        from jax.sharding import PartitionSpec as P
+    with jax.named_scope("qkv_proj"):
+        q = proj(p["wq"], H)
+        k_new = proj(p["wk"], KV)
+        v_new = proj(p["wv"], KV)
+        q = apply_rope(q, pos[None, None], cfg.rope_theta)
+        k_new = apply_rope(k_new, pos[None, None], cfg.rope_theta)
+        if cfg.decode_split_k:
+            # replicate the tiny per-token tensors over `model`; the cache
+            # stays sequence-sharded and attention contracts shard-locally
+            # (split-K).
+            from jax.sharding import PartitionSpec as P
 
-        spec = P(tuple(cfg.sp_axes) or None, None, None, None)
-        q = jax.lax.with_sharding_constraint(q, spec)
-        k_new = jax.lax.with_sharding_constraint(k_new, spec)
-        v_new = jax.lax.with_sharding_constraint(v_new, spec)
+            spec = P(tuple(cfg.sp_axes) or None, None, None, None)
+            q = jax.lax.with_sharding_constraint(q, spec)
+            k_new = jax.lax.with_sharding_constraint(k_new, spec)
+            v_new = jax.lax.with_sharding_constraint(v_new, spec)
     if cfg.defer_cache_write:
         # Read-only cache + separate fresh-token score: no dynamic write into
         # the sequence-sharded cache (which would force a full all-gather).
         # Grouped einsum: never materialize the G-times repeated cache.
-        groups = H // KV
-        qg = q.reshape(B, 1, KV, groups, hd)
-        s_c = jnp.einsum(
-            "bqkgd,bskd->bkgqs", qg, k_cache,
-            preferred_element_type=jnp.float32,
-        ) * hd ** -0.5  # (B, KV, G, 1, S)
-        mask = (slot_pos >= 0) & (slot_pos < pos)
-        if cfg.sliding_window is not None:
-            mask = mask & (slot_pos > pos - cfg.sliding_window)
-        s_c = jnp.where(mask[None, None, None, None, :], s_c, -1e30)
-        s_n = jnp.einsum(
-            "bqkgd,bqkd->bkgq", qg, k_new,
-            preferred_element_type=jnp.float32,
-        )[..., None] * hd ** -0.5  # (B, KV, G, 1, 1)
-        prob = jax.nn.softmax(jnp.concatenate([s_c, s_n], -1), axis=-1)
-        out_c = jnp.einsum(
-            "bkgqs,bskd->bqkgd", prob[..., :-1].astype(v_cache.dtype),
-            v_cache, preferred_element_type=jnp.float32,
-        )  # (B, 1, KV, G, hd) f32
-        p_new = prob[..., 0, -1]  # (B, KV, G)
-        out_n = p_new[:, None, :, :, None] \
-            * v_new.astype(jnp.float32)[:, :, :, None, :]
-        out = (out_c + out_n).reshape(B, 1, H, hd).astype(x.dtype)
-        return out.reshape(B, 1, H * hd) @ p["wo"]["w"], (k_new, v_new)
-    if slot is None:
-        # standalone call: derive the write slot from the config (decode_step
-        # passes the cache-derived slot so the two can never disagree)
-        slots = k_cache.shape[1]
-        ring = cfg.sliding_window is not None and cfg.sliding_window <= slots
-        slot = jnp.where(ring, pos % slots, jnp.minimum(pos, slots - 1))
-    k_cache = kv_lib.write_slot(k_cache, k_new, slot)
-    v_cache = kv_lib.write_slot(v_cache, v_new, slot)
-    out = decode_attention(
-        q, k_cache, v_cache, slot_pos, pos, window=cfg.sliding_window
-    )
-    return out.reshape(B, 1, H * hd) @ p["wo"]["w"], (k_cache, v_cache)
+        with jax.named_scope("attention"):
+            groups = H // KV
+            qg = q.reshape(B, 1, KV, groups, hd)
+            s_c = jnp.einsum(
+                "bqkgd,bskd->bkgqs", qg, k_cache,
+                preferred_element_type=jnp.float32,
+            ) * hd ** -0.5  # (B, KV, G, 1, S)
+            mask = (slot_pos >= 0) & (slot_pos < pos)
+            if cfg.sliding_window is not None:
+                mask = mask & (slot_pos > pos - cfg.sliding_window)
+            s_c = jnp.where(mask[None, None, None, None, :], s_c, -1e30)
+            s_n = jnp.einsum(
+                "bqkgd,bqkd->bkgq", qg, k_new,
+                preferred_element_type=jnp.float32,
+            )[..., None] * hd ** -0.5  # (B, KV, G, 1, 1)
+            prob = jax.nn.softmax(jnp.concatenate([s_c, s_n], -1), axis=-1)
+            out_c = jnp.einsum(
+                "bkgqs,bskd->bqkgd", prob[..., :-1].astype(v_cache.dtype),
+                v_cache, preferred_element_type=jnp.float32,
+            )  # (B, 1, KV, G, hd) f32
+            p_new = prob[..., 0, -1]  # (B, KV, G)
+            out_n = p_new[:, None, :, :, None] \
+                * v_new.astype(jnp.float32)[:, :, :, None, :]
+            out = (out_c + out_n).reshape(B, 1, H, hd).astype(x.dtype)
+        with jax.named_scope("out_proj"):
+            return out.reshape(B, 1, H * hd) @ p["wo"]["w"], (k_new, v_new)
+    with jax.named_scope("kv_write"):
+        if slot is None:
+            # standalone call: derive the write slot from the config
+            # (decode_step passes the cache-derived slot so the two can
+            # never disagree)
+            slots = k_cache.shape[1]
+            ring = cfg.sliding_window is not None and cfg.sliding_window <= slots
+            slot = jnp.where(ring, pos % slots, jnp.minimum(pos, slots - 1))
+        k_cache = kv_lib.write_slot(k_cache, k_new, slot)
+        v_cache = kv_lib.write_slot(v_cache, v_new, slot)
+    with jax.named_scope("attention"):
+        out = decode_attention(
+            q, k_cache, v_cache, slot_pos, pos, window=cfg.sliding_window
+        )
+    with jax.named_scope("out_proj"):
+        return out.reshape(B, 1, H * hd) @ p["wo"]["w"], (k_cache, v_cache)
 
 
 def _decode_attn_mla(p, x, cfg, c_kv_cache, k_rope_cache, slot_pos, pos):
@@ -641,9 +655,15 @@ def gr_decode_step(
 
 
 def decode_step(params, cache, tokens: jax.Array, cfg: TransformerConfig):
-    """One autoregressive step. tokens (B, 1) -> (logits (B,1,V), new cache)."""
+    """One autoregressive step. tokens (B, 1) -> (logits (B,1,V), new cache).
+
+    Each piece of the step has a ``jax.named_scope`` (``embed``,
+    ``qkv_proj``, ``kv_write``, ``attention``, ``out_proj``, ``ffn``,
+    ``unembed``; DESIGN.md §9), so a profile splits a decode level by them.
+    """
     B = tokens.shape[0]
-    x = jnp.take(params["emb"], tokens, axis=0)  # (B, 1, D)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["emb"], tokens, axis=0)  # (B, 1, D)
     pos = cache.pos
     mla = cfg.attention == "mla"
     if mla:
@@ -652,32 +672,32 @@ def decode_step(params, cache, tokens: jax.Array, cfg: TransformerConfig):
     else:
         slots = cache.k.shape[2]
         ring = cache.ring
-    slot_pos, write_slot = kv_lib.advance_positions(
-        cache.slot_pos, pos, slots, ring=False if mla else ring
-    )
+    with jax.named_scope("kv_write"):
+        slot_pos, write_slot = kv_lib.advance_positions(
+            cache.slot_pos, pos, slots, ring=False if mla else ring
+        )
 
     def body(x, inp):
+        p, ca, cb = inp
+        with jax.named_scope("qkv_proj"):
+            h = rms_norm(p["ln_attn"], x, cfg.norm_eps)
         if mla:
-            p, ck, kr = inp
-            h = rms_norm(p["ln_attn"], x, cfg.norm_eps)
-            attn_out, (ck, kr) = _decode_attn_mla(
-                p["attn"], h, cfg, ck, kr, slot_pos, pos
+            attn_out, new_cache = _decode_attn_mla(
+                p["attn"], h, cfg, ca, cb, slot_pos, pos
             )
-            new_cache = (ck, kr)
         else:
-            p, kc, vc = inp
-            h = rms_norm(p["ln_attn"], x, cfg.norm_eps)
-            attn_out, (kc, vc) = _decode_attn_gqa(
-                p["attn"], h, cfg, kc, vc, slot_pos, pos, slot=write_slot
+            attn_out, new_cache = _decode_attn_gqa(
+                p["attn"], h, cfg, ca, cb, slot_pos, pos, slot=write_slot
             )
-            new_cache = (kc, vc)
-        x = x + attn_out
-        h = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
-        if "moe" in p:
-            y, _ = moe_ffn(p["moe"], h, cfg.moe)
-            x = x + y
-        else:
-            x = x + swiglu(p["ffn"], h)
+        with jax.named_scope("out_proj"):
+            x = x + attn_out
+        with jax.named_scope("ffn"):
+            h = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
+            if "moe" in p:
+                y, _ = moe_ffn(p["moe"], h, cfg.moe)
+                x = x + y
+            else:
+                x = x + swiglu(p["ffn"], h)
         return x, new_cache
 
     n_dense = (cfg.moe.first_dense_layers if cfg.moe else cfg.n_layers)
@@ -700,8 +720,9 @@ def decode_step(params, cache, tokens: jax.Array, cfg: TransformerConfig):
         jnp.concatenate([g[i] for g in new_arrays], axis=0)
         for i in range(2)
     )
-    x_cur = rms_norm(params["final_norm"], x_cur, cfg.norm_eps)
-    logits = (x_cur @ _unemb(params, cfg)).astype(jnp.float32)
+    with jax.named_scope("unembed"):
+        x_cur = rms_norm(params["final_norm"], x_cur, cfg.norm_eps)
+        logits = (x_cur @ _unemb(params, cfg)).astype(jnp.float32)
     if cfg.defer_cache_write:
         # caches untouched; pending per-layer k/v stacks returned for the
         # serving layer to commit at block granularity.

@@ -15,18 +15,8 @@ from repro.observability.metrics import (
     MetricsRegistry,
     start_http_server,
 )
-from repro.observability.profiling import (
-    annotate,
-    maybe_trace,
-    named_scope,
-    trace_capture,
-)
-from repro.observability.timing import (
-    RecompileDetector,
-    StepStats,
-    StepTimer,
-    compile_events,
-)
+from repro.observability.profiling import annotate
+from repro.observability.timing import StepStats, StepTimer, compile_events
 
 __all__ = [
     "Counter",
@@ -38,12 +28,8 @@ __all__ = [
     "start_http_server",
     "StepTimer",
     "StepStats",
-    "RecompileDetector",
     "compile_events",
     "annotate",
-    "named_scope",
-    "trace_capture",
-    "maybe_trace",
     "record_policy",
 ]
 

@@ -1,33 +1,19 @@
-"""jax.profiler integration: trace annotations + programmatic capture.
+"""Host-side profiler annotations.
 
-Two kinds of markers, matching the two sides of the jit boundary:
-
-  * :func:`named_scope` (re-exported ``jax.named_scope``) — *trace-time*
-    scopes INSIDE jitted code.  They attach names to HLO ops so profiler
-    timelines show ``prefill`` / ``constraint_mask`` / ``beam_advance``
-    instead of fusion soup; they are metadata only, change no computation,
-    and cost nothing at runtime (the golden-trace suite pins this:
-    fixtures generated before any scope existed still match bit for bit).
-  * :func:`annotate` — *host-side* ``jax.profiler.TraceAnnotation`` around
-    compiled calls (a serve batch, a refresh rebuild).  ~1 us per enter /
-    exit when a trace is active, nothing device-side.
-
-:func:`trace_capture` wraps programmatic ``jax.profiler.start_trace`` /
-``stop_trace`` for the opt-in "capture one decode step" workflow
-(DESIGN.md §9): pass a directory, run the region, open the dump with
-TensorBoard or Perfetto.  :func:`maybe_trace` makes it flag-friendly —
-``None`` disables capture with zero overhead.
+:func:`annotate` opens a ``jax.profiler.TraceAnnotation`` around a phase of
+the serving loop (admission, dispatch, readback): a host span on the
+profiler's clock, beside the device ops, so an idle gap of the device can be
+put down to what the host was doing.  About 1 us per enter / exit, nothing
+device-side; it adds no ``named_scope``.  Names inside jitted code are
+``jax.named_scope``s, written where the code is (DESIGN.md §9).
 """
 from __future__ import annotations
 
 import contextlib
-from typing import Optional
 
 import jax
 
-__all__ = ["annotate", "named_scope", "trace_capture", "maybe_trace"]
-
-named_scope = jax.named_scope
+__all__ = ["annotate"]
 
 
 def annotate(name: str):
@@ -37,23 +23,3 @@ def annotate(name: str):
         return jax.profiler.TraceAnnotation(name)
     except Exception:  # pragma: no cover - profiler backend missing
         return contextlib.nullcontext()
-
-
-@contextlib.contextmanager
-def trace_capture(log_dir: str, *, create_perfetto_link: bool = False):
-    """Capture a profiler trace of the enclosed region into ``log_dir``."""
-    jax.profiler.start_trace(
-        log_dir, create_perfetto_link=create_perfetto_link
-    )
-    try:
-        yield log_dir
-    finally:
-        jax.profiler.stop_trace()
-
-
-def maybe_trace(log_dir: Optional[str]):
-    """``trace_capture(log_dir)`` when a directory is given, else a no-op
-    context — the shape CLI flags want (``--trace-dir`` defaulting off)."""
-    if log_dir:
-        return trace_capture(log_dir)
-    return contextlib.nullcontext()

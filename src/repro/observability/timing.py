@@ -18,10 +18,10 @@ structural change pays tracing + XLA compilation.  Naive
 Recompile detection rides on ``jax.monitoring``'s ``backend_compile``
 duration events — the same signal the test suite's zero-recompile
 assertions use — counted by one process-global listener
-(:func:`compile_events`).  :class:`RecompileDetector` snapshots the counter
-so serving engines can turn the DESIGN.md §4 "hot swaps never recompile"
-*test assertion* into a *monitored invariant*: every compile observed
-outside an expected window (first batch, cold swap) increments an
+(:func:`compile_events`).  Serving engines read its deltas around each
+batch to turn the DESIGN.md §4 "hot swaps never recompile" *test
+assertion* into a *monitored invariant*: every compile observed outside an
+expected window (first batch, cold swap) increments an
 ``unexpected``-labeled counter that should read 0 forever.
 """
 from __future__ import annotations
@@ -33,7 +33,7 @@ from typing import Optional
 
 import numpy as np
 
-__all__ = ["StepTimer", "StepStats", "RecompileDetector", "compile_events"]
+__all__ = ["StepTimer", "StepStats", "compile_events"]
 
 _compile_lock = threading.Lock()
 _compile_events = 0
@@ -70,34 +70,6 @@ def compile_events() -> int:
     _ensure_listener()
     with _compile_lock:
         return _compile_events
-
-
-class RecompileDetector:
-    """Snapshot-delta view of :func:`compile_events`.
-
-    >>> det = RecompileDetector()   # arms (and snapshots) immediately
-    >>> ...                         # run the supposedly-stable step
-    >>> det.count                   # 0 unless something compiled
-
-    Also usable as a context manager; ``reset()`` re-arms in place.
-    """
-
-    def __init__(self):
-        self._start = compile_events()
-
-    def reset(self) -> None:
-        self._start = compile_events()
-
-    @property
-    def count(self) -> int:
-        return compile_events() - self._start
-
-    def __enter__(self) -> "RecompileDetector":
-        self.reset()
-        return self
-
-    def __exit__(self, *exc) -> None:
-        pass
 
 
 @dataclasses.dataclass

@@ -435,37 +435,41 @@ class ServingEngine:
         self._m.record_shed(queue, results)  # submit-time refusals
         while len(queue):
             t_admit = time.monotonic()
-            queue.shed_expired()
-            batch = queue.pop_batch(self.batch_size)
-            self._m.record_shed(queue, results)
-            self._m.sample_queue(queue)
-            if not batch:
-                continue
-            version, cold = None, False
-            if self.registry is not None:
-                version, cold = self._install_current_store()
-            # A plain single-matrix retriever serves every request under the
-            # one set: constraint ids stay host-side and must all be 0.
-            num_sets = self.retriever.num_sets
-            hist = np.zeros((self.batch_size, S), np.int32)
-            cids = np.zeros(self.batch_size, np.int32)
-            for i, r in enumerate(batch):
-                hist[i, : min(r.prompt.shape[0], S)] = r.prompt[:S]
-                limit = num_sets if num_sets is not None else 1
-                if not 0 <= r.constraint_id < limit:
-                    raise ValueError(
-                        f"request {r.rid}: constraint_id {r.constraint_id} "
-                        f"outside [0, {limit})"
-                    )
-                cids[i] = r.constraint_id
+            with annotate("serve.admit"):
+                queue.shed_expired()
+                batch = queue.pop_batch(self.batch_size)
+                self._m.record_shed(queue, results)
+                self._m.sample_queue(queue)
+                if not batch:
+                    continue
+                version, cold = None, False
+                if self.registry is not None:
+                    # before packing: the installed store decides which
+                    # constraint ids a request may name
+                    with annotate("serve.install"):
+                        version, cold = self._install_current_store()
+                # A plain single-matrix retriever serves every request under
+                # the one set: constraint ids stay host-side and must all
+                # be 0.
+                num_sets = self.retriever.num_sets
+                hist = np.zeros((self.batch_size, S), np.int32)
+                cids = np.zeros(self.batch_size, np.int32)
+                for i, r in enumerate(batch):
+                    hist[i, : min(r.prompt.shape[0], S)] = r.prompt[:S]
+                    limit = num_sets if num_sets is not None else 1
+                    if not 0 <= r.constraint_id < limit:
+                        raise ValueError(
+                            f"request {r.rid}: constraint_id "
+                            f"{r.constraint_id} outside [0, {limit})"
+                        )
+                    cids[i] = r.constraint_id
             c0 = compile_events()
             try:
                 fire("decode.slow_step")  # delay => slow batch; error => fail
-                with annotate("serve_batch"):
-                    beams, scores = self.retriever.retrieve(
-                        hist,
-                        constraint_ids=cids if num_sets is not None else None,
-                    )
+                beams, scores = self.retriever.retrieve(
+                    hist,
+                    constraint_ids=cids if num_sets is not None else None,
+                )
             except InjectedFault:
                 # A failed decode step degrades to failed *requests*, never
                 # to unconstrained decoding or an engine crash: the batch is
@@ -489,22 +493,23 @@ class ServingEngine:
             if self.breaker is not None:
                 self.breaker.record_success()
             t_done = time.monotonic()
-            self._m.record_batch(
-                n_active=len(batch), slots=self.batch_size,
-                steps=self.retriever.L, dt=t_done - t_admit,
-                compiles=compile_events() - c0,
-                expected=cold or self._served_batches == 0,
-            )
-            self._served_batches += 1
-            for i, r in enumerate(batch):
-                results[r.rid] = {
-                    "sids": beams[i],
-                    "scores": scores[i],
-                    "constraint_id": r.constraint_id,
-                    "store_version": version,
-                    **self._m.record_request(r, t_admit, t_done,
-                                             n_out=self.retriever.L),
-                }
+            with annotate("serve.record"):
+                self._m.record_batch(
+                    n_active=len(batch), slots=self.batch_size,
+                    steps=self.retriever.L, dt=t_done - t_admit,
+                    compiles=compile_events() - c0,
+                    expected=cold or self._served_batches == 0,
+                )
+                self._served_batches += 1
+                for i, r in enumerate(batch):
+                    results[r.rid] = {
+                        "sids": beams[i],
+                        "scores": scores[i],
+                        "constraint_id": r.constraint_id,
+                        "store_version": version,
+                        **self._m.record_request(r, t_admit, t_done,
+                                                 n_out=self.retriever.L),
+                    }
         self._m.record_shed(queue, results)
         self._m.sample_queue(queue)
         return results

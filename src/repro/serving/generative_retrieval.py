@@ -33,6 +33,7 @@ from repro.configs.base import TransformerConfig
 from repro.core import beam_search
 from repro.decoding import as_policy
 from repro.models import transformer
+from repro.observability import annotate
 
 __all__ = ["GenerativeRetriever"]
 
@@ -110,10 +111,12 @@ class GenerativeRetriever:
                     f"range [{cids_np.min()}, {cids_np.max()}]"
                 )
             cids = jnp.asarray(cids_np)
-        tokens, scores = self._retrieve_jit(
-            self.params, jnp.asarray(history), self.policy, cids
-        )
-        return np.asarray(tokens), np.asarray(scores)
+        with annotate("retrieve.dispatch"):
+            tokens, scores = self._retrieve_jit(
+                self.params, jnp.asarray(history), self.policy, cids
+            )
+        with annotate("retrieve.readback"):
+            return np.asarray(tokens), np.asarray(scores)
 
     def compile_step(self, batch: int, prompt_width: int):
         """Compile the single-matrix retrieval step ahead of time for

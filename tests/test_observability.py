@@ -13,11 +13,14 @@ The load-bearing claims:
      bit-identical to calling the retriever directly (metrics cannot touch
      the jitted computation).
 """
+import dataclasses
 import json
 import logging
+import re
 import urllib.request
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -35,9 +38,7 @@ from repro.decoding import DecodePolicy
 from repro.models import transformer
 from repro.observability import (
     MetricsRegistry,
-    RecompileDetector,
     StepTimer,
-    compile_events,
     record_policy,
     start_http_server,
 )
@@ -176,18 +177,93 @@ def test_step_timer_splits_warmup_and_steady_compiles():
     assert s["steady_compiles"] == 0 and s["name"] == "t"
 
 
-def test_recompile_detector_fires_only_on_compiles():
-    f = jax.jit(lambda x: x + 1.0)
-    x = np.ones(11, np.float32)
-    f(x)  # compile outside the armed window
-    det = RecompileDetector()
-    f(x)
-    assert det.count == 0
-    f(np.ones(13, np.float32))  # new shape: retrace
-    assert det.count >= 1
-    det.reset()
-    assert det.count == 0
-    assert compile_events() >= 1
+# ---------------------------------------------------------------------------
+# profiler names: decoder scopes in the HLO, engine spans on the host
+# ---------------------------------------------------------------------------
+PIECES = ("embed", "qkv_proj", "kv_write", "attention", "out_proj", "ffn",
+          "unembed")
+
+
+def _level_op_names(hlo_text):
+    """(opcode, op_name) of every HLO op whose op_name lies in a decode
+    level."""
+    out = []
+    for line in hlo_text.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        if name and re.search(r"(^|/)decode_logits_L\d+/", name.group(1)):
+            opcode = re.search(r"= \S+ ([\w-]+)\(", line)
+            out.append((opcode.group(1) if opcode else "", name.group(1)))
+    return out
+
+
+@pytest.mark.parametrize("defer", [False, True], ids=["eager", "deferred"])
+def test_decode_level_ops_carry_a_piece_name(defer, rng):
+    """Every matmul of a decode level is named by the piece of the layer
+    it belongs to, and each of the seven names reaches the optimized HLO.
+    The retriever's beam search carries the written cache, so the deferred
+    write is compiled as the bare decode step inside a level's scope."""
+    cfg = dataclasses.replace(smoke_config("static-gr"),
+                              defer_cache_write=defer)
+    params = transformer.init_params(cfg, jax.random.key(0))
+    if defer:
+        cache = jax.eval_shape(lambda: transformer.init_cache(cfg, 2, 12))
+
+        def level(p, c, t):
+            with jax.named_scope("decode_logits_L1"):
+                return transformer.decode_step(p, c, t, cfg)
+
+        lowered = jax.jit(level).lower(
+            params, cache, jax.ShapeDtypeStruct((2, 1), jnp.int32))
+    else:
+        V = 16
+        tm = TransitionMatrix.from_sids(make_sids(rng, 40, V, 3), V)
+        gr = GenerativeRetriever(params, cfg, tm, sid_length=3, sid_vocab=V,
+                                 beam_size=4)
+        lowered = gr._retrieve_jit.lower(
+            params, jax.ShapeDtypeStruct((2, 8), jnp.int32), gr.policy, None)
+    ops = _level_op_names(lowered.compile().as_text())
+    dots = [n for op, n in ops if op == "dot"]
+    assert dots
+    for n in dots:
+        assert set(n.split("/")) & {"qkv_proj", "attention", "out_proj",
+                                    "ffn", "unembed"}, n
+    for piece in PIECES:
+        assert any(piece in n.split("/") for _, n in ops), piece
+
+
+def test_batch_engine_host_spans_in_order(small_lm, rng, tmp_path):
+    """One traced batch: the host plane holds the engine's phases, in the
+    order they run, with the store install inside admission."""
+    params, cfg = small_lm
+    eng, _, _ = _build_engine(params, cfg, rng, batch_size=2)
+
+    def one_batch():
+        q = RequestQueue()
+        for i in range(2):
+            q.submit(rng.integers(0, cfg.vocab_size, (8,)), n_tokens=L,
+                     constraint_id=i)
+        return eng.serve(q)
+
+    one_batch()  # compiles outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        assert all("sids" in r for r in one_batch().values())
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    spans = {}
+    for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    spans.setdefault(e.name, (e.start_ns,
+                                              e.start_ns + e.duration_ns))
+    order = ["serve.admit", "retrieve.dispatch", "retrieve.readback",
+             "serve.record"]
+    assert set(order) <= set(spans), sorted(spans)
+    starts = [spans[n][0] for n in order]
+    assert starts == sorted(starts)
+    for a, b in zip(order, order[1:]):
+        assert spans[a][1] <= spans[b][0], (a, b)
+    admit, install = spans["serve.admit"], spans["serve.install"]
+    assert admit[0] <= install[0] and install[1] <= admit[1]
 
 
 # ---------------------------------------------------------------------------
