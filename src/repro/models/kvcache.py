@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 
 __all__ = [
-    "KVCache", "MLACache", "init_kv_cache", "init_mla_cache",
+    "KVCache", "MLACache", "SharedHistoryCache", "init_kv_cache",
+    "init_mla_cache",
     "init_page_pool", "scatter_pages", "gather_pages", "pages_for",
 ]
 
@@ -48,6 +49,19 @@ class MLACache:
     k_rope: jax.Array  # (L, B, S, rope_dim) shared decoupled keys
     slot_pos: jax.Array  # (S,)
     pos: jax.Array  # ()
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class SharedHistoryCache:
+    """Generative retrieval's decode cache (DESIGN.md §14): each request's
+    history held once, shared by its M beams, and each beam's SID suffix."""
+
+    hist_k: jax.Array  # (L, B, S, KVH, Dh)
+    hist_v: jax.Array  # (L, B, S, KVH, Dh)
+    sfx_k: jax.Array  # (L, B*M, Ls, KVH, Dh), or (L, B, M, Ls, KVH, Dh)
+    sfx_v: jax.Array
+    step: jax.Array  # () suffix column of the next token
 
 
 def init_kv_cache(

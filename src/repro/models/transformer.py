@@ -17,6 +17,7 @@ materialized.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional
 
@@ -25,7 +26,11 @@ import jax.numpy as jnp
 
 from repro.configs.base import TransformerConfig
 from repro.models import kvcache as kv_lib
-from repro.models.attention import chunked_causal_attention, decode_attention
+from repro.models.attention import (
+    NEG,
+    chunked_causal_attention,
+    decode_attention,
+)
 from repro.models.layers import (
     apply_rope,
     dense_init,
@@ -39,8 +44,8 @@ from repro.models.moe import moe_ffn, moe_init
 
 __all__ = [
     "init_params", "param_specs", "forward", "lm_loss",
-    "lm_loss_trie_aware", "prefill", "decode_step", "paged_decode_step",
-    "init_cache",
+    "lm_loss_trie_aware", "prefill", "decode_step", "gr_decode_step",
+    "paged_decode_step", "init_cache",
 ]
 
 
@@ -547,120 +552,135 @@ def _decode_attn_mla(p, x, cfg, c_kv_cache, k_rope_cache, slot_pos, pos):
     return out.reshape(B, 1, H * vd) @ p["wo"], (c_kv_cache, k_rope_cache)
 
 
+def _shared_history_attention(q, hist_k, hist_v, sfx_k, sfx_v, sfx_valid):
+    """Attention of each row's M beams over the row's history, held once,
+    and over each beam's own decoded suffix, under one softmax.
+
+    q (B, M, H, hd); hist_k/v (B, S, KV, hd); sfx_k/v (B, M, Ls, KV, hd);
+    sfx_valid (B, Ls) marks the suffix columns each row attends (every
+    history column is attended).  The (KV, G)-factored query contracts both
+    caches directly, so neither is repeated per beam or per head group.
+    Returns (B, M, H, hd) in q's dtype.
+    """
+    B, M, H, hd = q.shape
+    S, KV = hist_k.shape[1], hist_k.shape[2]
+    qg = q.reshape(B, M, KV, H // KV, hd)
+    scale = hd ** -0.5
+    s_h = jnp.einsum("bmkgd,bskd->bkmgs", qg, hist_k,
+                     preferred_element_type=jnp.float32) * scale
+    s_s = jnp.einsum("bmkgd,bmskd->bkmgs", qg, sfx_k,
+                     preferred_element_type=jnp.float32) * scale
+    s_s = jnp.where(sfx_valid[:, None, None, None, :], s_s, NEG)
+    p = jax.nn.softmax(jnp.concatenate([s_h, s_s], axis=-1), axis=-1)
+    p = p.astype(hist_v.dtype)
+    out = jnp.einsum("bkmgs,bskd->bmkgd", p[..., :S], hist_v,
+                     preferred_element_type=jnp.float32) + jnp.einsum(
+        "bkmgs,bmskd->bmkgd", p[..., S:], sfx_v,
+        preferred_element_type=jnp.float32)
+    return out.reshape(B, M, H, hd).astype(q.dtype)
+
+
 def gr_decode_step(
     params,
-    hist_k: jax.Array,  # (L, B, S_h, KV, Dh) shared user-history cache
+    hist_k: jax.Array,  # (n_layers, B, S, KV, hd) history, one per request
     hist_v: jax.Array,
-    beam_k: jax.Array,  # (L, B*M, S_sid, KV, Dh) per-beam SID cache
+    beam_k: jax.Array,  # (n_layers, B*M | B, M, Ls, KV, hd) SID suffixes
     beam_v: jax.Array,
     tokens: jax.Array,  # (B*M, 1)
-    sid_step: jax.Array,  # () current SID decode step (0..L_sid-1)
+    sid_step: jax.Array,  # () suffix column of this step's token
     cfg: TransformerConfig,
 ):
-    """Prefix-shared generative-retrieval decode (beyond-paper serving opt).
+    """One decode level of generative retrieval: the batch and SPMD
+    retrievers' served step (DESIGN.md §14).
 
-    The user-history KV is computed once per request and *shared* across all
-    M beams; only the short per-beam SID suffix is beam-private.  Attention
-    runs over the concatenation [history | suffix] with a single softmax.
-    Cuts GR decode KV memory by ~M/(1 + L_sid/S_h) (~64x at M=70, S_h=256).
+    Each request's history K/V is held once and shared by its M beams;
+    only the SID suffix is beam-private, in the flat ``(n_layers, B*M, ...)``
+    or the batched ``(n_layers, B, M, ...)`` layout, whichever the caller
+    keeps.  The token at ``sid_step`` sits at position ``S + sid_step``, its
+    K/V goes into suffix column ``sid_step``, and every beam attends its
+    whole history and suffix columns ``0..sid_step`` under one softmax.
+    Dense-FFN GQA/MHA decoders only: no MLA, no MoE group, and no sliding
+    window shorter than ``S + Ls``.
+
+    The pieces carry :func:`decode_step`'s ``jax.named_scope`` names
+    (``embed``, ``qkv_proj``, ``kv_write``, ``attention``, ``out_proj``,
+    ``ffn``, ``unembed``; DESIGN.md §9).
+
+    Returns ``(logits (B*M, 1, vocab), new beam_k, new beam_v)``.
     """
     BM = tokens.shape[0]
-    B = hist_k.shape[1]
+    n, B, S = hist_k.shape[:3]
     M = BM // B
-    x = jnp.take(params["emb"], tokens, axis=0)  # (BM, 1, D)
-    hd = cfg.resolved_head_dim()
-    H, KV = cfg.n_heads, cfg.n_kv_heads
-    groups = H // KV
-    s_hist = hist_k.shape[2]
-    s_sid = beam_k.shape[3] if cfg.gr_batched_beams else beam_k.shape[2]
-    pos = s_hist + sid_step
+    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim()
+    Ls = beam_k.shape[-3]
+    pos = S + sid_step
+    sfx_valid = jnp.broadcast_to(jnp.arange(Ls) <= sid_step, (B, Ls))
+    with jax.named_scope("embed"):
+        x = jnp.take(params["emb"], tokens, axis=0)  # (BM, 1, D)
 
-    def body(x, inp):
-        p, hk, hv, bk, bv = inp
+    def body(carry, inp):
+        x, bk, bv = carry
+        p, hk, hv, i = inp
         a = p["attn"]
-        h = rms_norm(p["ln_attn"], x, cfg.norm_eps)
+        with jax.named_scope("qkv_proj"):
+            h = rms_norm(p["ln_attn"], x, cfg.norm_eps)
 
-        def proj(pp, width):
-            y = h @ pp["w"]
-            if "b" in pp:
-                y = y + pp["b"]
-            return y.reshape(BM, 1, width, hd)
+            def proj(pp, width):
+                y = h @ pp["w"]
+                if "b" in pp:
+                    y = y + pp["b"]
+                return y.reshape(BM, 1, width, hd)
 
-        q = apply_rope(proj(a["wq"], H), pos[None, None], cfg.rope_theta)
-        k_new = apply_rope(proj(a["wk"], KV), pos[None, None], cfg.rope_theta)
-        v_new = proj(a["wv"], KV)
-        slot = jnp.minimum(sid_step, s_sid - 1)
-        if cfg.gr_batched_beams:
-            # bk/bv: (B, M, S_sid, KV, hd) — slot write along axis 2
-            bk = jax.lax.dynamic_update_slice_in_dim(
-                bk, k_new.reshape(B, M, 1, KV, hd).astype(bk.dtype), slot, 2)
-            bv = jax.lax.dynamic_update_slice_in_dim(
-                bv, v_new.reshape(B, M, 1, KV, hd).astype(bv.dtype), slot, 2)
-        else:
-            bk = kv_lib.write_slot(bk, k_new, slot)
-            bv = kv_lib.write_slot(bv, v_new, slot)
+            q = apply_rope(proj(a["wq"], H), pos[None, None], cfg.rope_theta)
+            k_new = apply_rope(proj(a["wk"], KV), pos[None, None],
+                               cfg.rope_theta)
+            v_new = proj(a["wv"], KV)
+        with jax.named_scope("kv_write"):
+            at = (i, 0, 0, sid_step, 0, 0)
+            bk = jax.lax.dynamic_update_slice(
+                bk, k_new.reshape(1, B, M, 1, KV, hd).astype(bk.dtype), at)
+            bv = jax.lax.dynamic_update_slice(
+                bv, v_new.reshape(1, B, M, 1, KV, hd).astype(bv.dtype), at)
+        with jax.named_scope("attention"):
+            out = _shared_history_attention(
+                q.reshape(B, M, H, hd), hk, hv, bk[i], bv[i], sfx_valid)
+        with jax.named_scope("out_proj"):
+            x = x + out.reshape(BM, 1, H * hd) @ a["wo"]["w"]
+        with jax.named_scope("ffn"):
+            x = x + swiglu(p["ffn"], rms_norm(p["ln_ffn"], x, cfg.norm_eps))
+        return (x, bk, bv), None
 
-        def rep(t, axis=2):
-            return jnp.repeat(t, groups, axis=axis) if groups > 1 else t
-
-        # scores over shared history (broadcast across beams) + own suffix
-        qb = q.reshape(B, M, H, hd)
-        s1 = jnp.einsum(
-            "bmhd,bkhd->bmhk", qb, rep(hk), preferred_element_type=jnp.float32
-        ) * hd ** -0.5  # (B, M, H, S_h)
-        if cfg.gr_batched_beams:
-            s2 = jnp.einsum(
-                "bmhd,bmshd->bmhs", qb, rep(bk, axis=3),
-                preferred_element_type=jnp.float32,
-            ) * hd ** -0.5
-        else:
-            s2 = jnp.einsum(
-                "nqhd,nkhd->nhqk", q, rep(bk), preferred_element_type=jnp.float32
-            )[:, :, 0, :].reshape(B, M, H, s_sid) * hd ** -0.5
-        sid_mask = jnp.arange(s_sid) <= sid_step
-        s2 = jnp.where(sid_mask[None, None, None, :], s2, -1e30)
-        s = jnp.concatenate([s1, s2], axis=-1)
-        prob = jax.nn.softmax(s, axis=-1)
-        p1, p2 = prob[..., :s_hist], prob[..., s_hist:]
-        o1 = jnp.einsum("bmhk,bkhd->bmhd", p1.astype(hv.dtype), rep(hv),
-                        preferred_element_type=jnp.float32)
-        if cfg.gr_batched_beams:
-            o2 = jnp.einsum(
-                "bmhs,bmshd->bmhd", p2.astype(bv.dtype), rep(bv, axis=3),
-                preferred_element_type=jnp.float32,
-            )
-        else:
-            o2 = jnp.einsum(
-                "nhk,nkhd->nhd",
-                p2.reshape(BM, H, s_sid).astype(bv.dtype), rep(bv),
-                preferred_element_type=jnp.float32,
-            ).reshape(B, M, H, hd)
-        out = (o1 + o2).reshape(BM, 1, H * hd).astype(x.dtype)
-        x = x + out @ a["wo"]["w"]
-        hh = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
-        if "moe" in p:
-            y, _ = moe_ffn(p["moe"], hh, cfg.moe)
-            x = x + y
-        else:
-            x = x + swiglu(p["ffn"], hh)
-        return x, (bk, bv)
-
-    x, (new_bk, new_bv) = jax.lax.scan(
-        body, x, (params["dense_layers"], hist_k, hist_v, beam_k, beam_v),
+    # The suffixes ride in the carry, each layer's slot written in place:
+    # as the scan's xs/ys every layer's slice would be sliced out and
+    # stacked back, a copy each way per layer and level.
+    batched = (n, B, M, Ls, KV, hd)
+    (x, new_bk, new_bv), _ = jax.lax.scan(
+        body, (x, beam_k.reshape(batched), beam_v.reshape(batched)),
+        (params["dense_layers"], hist_k, hist_v, jnp.arange(n)),
         unroll=cfg.layer_unroll,
     )
-    x = rms_norm(params["final_norm"], x, cfg.norm_eps)
-    logits = (x @ _unemb(params, cfg)).astype(jnp.float32)  # (BM, 1, V)
-    return logits, new_bk, new_bv
+    with jax.named_scope("unembed"):
+        x = rms_norm(params["final_norm"], x, cfg.norm_eps)
+        logits = (x @ _unemb(params, cfg)).astype(jnp.float32)  # (BM, 1, V)
+    return logits, new_bk.reshape(beam_k.shape), new_bv.reshape(beam_v.shape)
 
 
 def decode_step(params, cache, tokens: jax.Array, cfg: TransformerConfig):
     """One autoregressive step. tokens (B, 1) -> (logits (B,1,V), new cache).
 
+    A :class:`~repro.models.kvcache.SharedHistoryCache` takes
+    :func:`gr_decode_step`, with one token per beam (tokens (B*M, 1)).
+
     Each piece of the step has a ``jax.named_scope`` (``embed``,
     ``qkv_proj``, ``kv_write``, ``attention``, ``out_proj``, ``ffn``,
     ``unembed``; DESIGN.md §9), so a profile splits a decode level by them.
     """
+    if isinstance(cache, kv_lib.SharedHistoryCache):
+        logits, sfx_k, sfx_v = gr_decode_step(
+            params, cache.hist_k, cache.hist_v, cache.sfx_k, cache.sfx_v,
+            tokens, cache.step, cfg)
+        return logits, dataclasses.replace(
+            cache, sfx_k=sfx_k, sfx_v=sfx_v, step=cache.step + 1)
     B = tokens.shape[0]
     with jax.named_scope("embed"):
         x = jnp.take(params["emb"], tokens, axis=0)  # (B, 1, D)
@@ -774,14 +794,13 @@ def paged_decode_step(
 
     Bit-identity contract (DESIGN.md §10, fuzz-asserted in
     ``tests/test_continuous.py``): for a row at level ``l >= 1`` with
-    ``pos = S + l - 1`` this computes exactly what the ``l``-th sequential
-    :func:`decode_step` computes for that row — the gathered history is
-    sliced to exactly ``hist_len`` columns and concatenated with the
-    ``Ls = L + 1``-column suffix, so the attention width ``S + L + 1``
-    matches the sequence-boundary engine's ``max_len`` and every reduction
-    keeps its shape.  Rows whose output is unused (level-0 or dead slots)
-    must point ``write_col`` at the trash column ``Ls - 1``, which no
-    in-range ``pos`` can ever attend to.
+    ``pos = S + l - 1`` this computes exactly what :func:`gr_decode_step`
+    computes at ``sid_step = l - 1`` for that row — the gathered history is
+    sliced to exactly ``hist_len`` columns and the suffix has the retriever's
+    ``Ls = L - 1`` columns, so both run :func:`_shared_history_attention` on
+    the same shapes and every reduction keeps its order.  Rows whose output
+    is unused (level-0 or dead slots) point ``write_col`` past the last
+    column (``Ls``), which writes nothing.
 
     Returns ``(logits (slots*M, 1, vocab), new_suffix_k, new_suffix_v)``.
     """
@@ -806,12 +825,9 @@ def paged_decode_step(
         )
     x = jnp.take(params["emb"], tokens.reshape(N, 1), axis=0)  # (N, 1, D)
     pos_row = jnp.repeat(pos, M)  # (N,)
-    pages = page_table.reshape(-1)
-    # synthetic slot positions: history cols 0..S-1 then suffix cols at
-    # S..S+Ls-1 — identical to the sequential cache's slot_pos for every
-    # column <= pos (prefill stamps 0..S-1, step l writes S+l-1), and the
-    # trash column S+Ls-1 > pos is always masked.
-    slot_positions = jnp.arange(S + Ls, dtype=jnp.int32)
+    # suffix column j holds position S + j
+    sfx_valid = (jnp.arange(Ls, dtype=jnp.int32)[None, :]
+                 <= (pos - S)[:, None])  # (slots, Ls)
     col_mask = (jnp.arange(Ls, dtype=jnp.int32)[None, None, :]
                 == write_col[:, None, None])  # (slots, 1, Ls)
 
@@ -841,19 +857,12 @@ def paged_decode_step(
             col_mask[..., None, None],
             v_new.reshape(slots, M, 1, KV, hd).astype(sv.dtype), sv,
         )
-        # history through the page table: one stored copy per slot, fanned
-        # out across beams only as a transient gather
-        hk = kv_lib.gather_pages(kp, page_table, S)
+        # history through the page table: one stored copy per slot, shared
+        # by its beams
+        hk = kv_lib.gather_pages(kp, page_table, S)  # (slots, S, KV, hd)
         hv = kv_lib.gather_pages(vp, page_table, S)
-        hk = jnp.repeat(hk, M, axis=0)  # (N, S, KV, hd)
-        hv = jnp.repeat(hv, M, axis=0)
-        kc = jnp.concatenate(
-            [hk, sk.reshape(N, Ls, KV, hd).astype(hk.dtype)], axis=1
-        )
-        vc = jnp.concatenate(
-            [hv, sv.reshape(N, Ls, KV, hd).astype(hv.dtype)], axis=1
-        )
-        out = decode_attention(q, kc, vc, slot_positions, pos_row)
+        out = _shared_history_attention(
+            q.reshape(slots, M, H, hd), hk, hv, sk, sv, sfx_valid)
         x = x + out.reshape(N, 1, H * hd) @ a["wo"]["w"]
         hh = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
         x = x + swiglu(p["ffn"], hh)

@@ -137,7 +137,7 @@ class ContinuousServingEngine:
         kv, hd = cfg.n_kv_heads, cfg.resolved_head_dim()
         self._k_pool, self._v_pool = kv_lib.init_page_pool(
             cfg.n_layers, n_pages, self.page_size, kv, hd, dtype=dtype)
-        Ls = self.L + 1
+        Ls = self.L - 1
         zeros6 = jnp.zeros(
             (cfg.n_layers, self.n_slots, self.M, Ls, kv, hd), dtype)
         self._suffix_k, self._suffix_v = zeros6, zeros6
@@ -210,17 +210,18 @@ class ContinuousServingEngine:
         """One decode level for every live slot, at its own level.
 
         Dead slots ride along (static shapes) with frozen outputs: their
-        suffix writes land in the trash column and their beam state is
-        select-frozen, so they cost compute but never change bits.
+        suffix write column lies past the suffix, so nothing is written, and
+        their beam state is select-frozen, so they cost compute but never
+        change bits.
         """
         slots, M, L = tokens.shape
-        S, V, Ls = self.S, self.V, self.L + 1
+        S, V, Ls = self.S, self.V, self.L - 1
         N = slots * M
         # a live row at level l >= 1 attends positions [0, S + l - 1] —
         # exactly the sequential cache's cur_pos at decode step l
         pos = S + jnp.clip(levels - 1, 0, L - 1)
         decoding = live & (levels > 0)
-        write_col = jnp.where(decoding, levels - 1, Ls - 1)
+        write_col = jnp.where(decoding, levels - 1, Ls)
         col = jnp.clip(levels - 1, 0, L - 1)
         last = jnp.take_along_axis(
             tokens, col[:, None, None], axis=2)[:, :, 0]

@@ -2,10 +2,12 @@
 
 ``GenerativeRetriever.retrieve`` takes user-history token sequences, prefills
 the model once per request, then runs the constrained beam search of
-Algorithm 1 over SID tokens.  Which constraint method masks each decode level
-is bound by a :class:`~repro.decoding.DecodePolicy` — the paper's STATIC
-matrix (100% compliance, §5.4), the stacked multi-tenant store, or any §5.2
-baseline all serve through this same jitted path.
+Algorithm 1 over SID tokens.  Each history's K/V is held once and shared by
+the request's beams; only the SID suffix is per beam (DESIGN.md §14).
+Which constraint method masks each decode level is bound by a
+:class:`~repro.decoding.DecodePolicy` — the paper's STATIC matrix (100%
+compliance, §5.4), the stacked multi-tenant store, or any §5.2 baseline all
+serve through this same jitted path.
 
 Multi-tenant mode (DESIGN.md §4): build the retriever with a stacked policy
 (``DecodePolicy.stacked(store)`` — or just pass the ConstraintStore) and a
@@ -23,6 +25,7 @@ plumbing here and cannot flip across a hot-swap.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Optional
 
 import jax
@@ -32,6 +35,7 @@ import numpy as np
 from repro.configs.base import TransformerConfig
 from repro.core import beam_search
 from repro.decoding import as_policy
+from repro.models import kvcache as kv_lib
 from repro.models import transformer
 from repro.observability import annotate
 
@@ -129,54 +133,44 @@ class GenerativeRetriever:
 
     def _retrieve_impl(self, params, history, policy, constraint_ids):
         B, S = history.shape
-        M = self.M
-        max_len = S + self.L + 1
+        M, cfg = self.M, self.cfg
+        Ls = self.L - 1  # a suffix column for each decode level after prefill
+        window = cfg.sliding_window
+        if (cfg.attention == "mla" or cfg.moe is not None
+                or (window is not None and window < S + Ls)):
+            raise NotImplementedError(
+                "the retrieval step shares each history across its beams "
+                "(transformer.gr_decode_step), which serves dense-FFN "
+                "GQA/MHA decoders whose window covers history and SID "
+                f"suffix ({S + Ls} positions); got attention="
+                f"{cfg.attention!r}, moe={cfg.moe is not None}, "
+                f"sliding_window={window}")
         # named_scope: trace-time profiler labels only (DESIGN.md §9) —
         # no runtime cost, no change to the computation.
         with jax.named_scope("prefill"):
-            pre_logits, cache = transformer.prefill(
-                params, history, self.cfg, max_len=max_len
-            )
-        # tile the request cache across beams: (L, B, ...) -> (L, B*M, ...)
-        def tile(a):
-            if a.ndim >= 2 and a.shape[1] == B:
-                return jnp.repeat(a, M, axis=1)
-            return a
+            pre_logits, cache = transformer.prefill(params, history, cfg)
+        # the history, (n_layers, B, S, KV, hd), once per request for its
+        # M beams; the suffix of each beam starts empty
+        suffix = jnp.zeros((cfg.n_layers, B * M, Ls) + cache.k.shape[3:],
+                           cache.k.dtype)
+        cache = kv_lib.SharedHistoryCache(
+            hist_k=cache.k, hist_v=cache.v, sfx_k=suffix, sfx_v=suffix,
+            step=jnp.zeros((), jnp.int32))
 
-        import dataclasses as dc
-
-        with jax.named_scope("cache_beam_tile"):
-            cache = dc.replace(
-                cache,
-                **{
-                    f.name: tile(getattr(cache, f.name))
-                    for f in dc.fields(cache)
-                    if f.name in ("k", "v", "c_kv", "k_rope")
-                },
-            )
-
-        def logits_fn(carry, last_tokens, step):
-            c = carry
-            toks = last_tokens.reshape(B * M, 1)
-            logits, c = transformer.decode_step(params, c, toks, self.cfg)
+        def logits_fn(c, last_tokens, step):
+            logits, c = transformer.decode_step(
+                params, c, last_tokens.reshape(B * M, 1), cfg)
             return logits[:, 0, : self.V].reshape(B, M, self.V), c
 
-        def gather_cache(c, beam_idx):
+        def gather_suffix(c, beam_idx):
             flat = (jnp.arange(B)[:, None] * M + beam_idx).reshape(-1)
-            import dataclasses as dc2
-
-            return dc2.replace(
-                c,
-                **{
-                    f.name: jnp.take(getattr(c, f.name), flat, axis=1)
-                    for f in dc2.fields(c)
-                    if f.name in ("k", "v", "c_kv", "k_rope")
-                },
-            )
+            return dataclasses.replace(
+                c, sfx_k=jnp.take(c.sfx_k, flat, axis=1),
+                sfx_v=jnp.take(c.sfx_v, flat, axis=1))
 
         state, _ = beam_search(
             logits_fn, cache, B, M, self.L, policy,
-            carry_gather_fn=gather_cache,
+            carry_gather_fn=gather_suffix,
             first_logits=pre_logits[:, 0, : self.V],
             constraint_ids=constraint_ids,
         )
