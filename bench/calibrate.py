@@ -27,12 +27,9 @@ from run import spec_lib, run_and_check  # noqa: E402  (bench/ on the path)
 
 
 def control_numbers(cell, seed, sids, sample, lp):
-    import importlib
+    from bench import check, system
 
-    from bench import check
-
-    ref = importlib.import_module(
-        f"bench.references.{cell.config['reference']}")
+    ref = system.reference(cell.config)
     prompts = np.stack([r.prompt for r in sample])
     beams = np.stack([r.sids for r in sample]).astype(np.int64)
     lp8 = ref.logprobs(cell.config, seed, prompts, beams, precision="fp8")

@@ -12,7 +12,7 @@ Weights are random data made from the seed by this module's generator
 (an embedding of N(0, 0.02^2); He-normal matrices over their rows; RMSNorm
 scales of one).  :func:`weights` makes all of them in the served dtype for
 the system under test (``bench/system.py`` places them in the program's
-parameter tree); the reference draws the same values again, layer by layer,
+parameter tree by :func:`placement`); the reference draws the same values again, layer by layer,
 and widens them to float32.  It takes no array from the program.
 
 ``precision="fp8"`` is the control: every matmul operand is rounded to
@@ -32,6 +32,27 @@ import jax.numpy as jnp
 import numpy as np
 
 F8_MAX = 448.0  # largest finite float8 e4m3
+
+# the program's parameter leaves, by path, and the drawn weight each holds,
+# by its path in weights_from_key's tree; RMSNorm scales hold ones
+PROGRAM_LEAVES = {
+    ("emb",): ("emb",),
+    ("dense_layers", "attn", "wq", "w"): ("layers", "wq"),
+    ("dense_layers", "attn", "wk", "w"): ("layers", "wk"),
+    ("dense_layers", "attn", "wv", "w"): ("layers", "wv"),
+    ("dense_layers", "attn", "wo", "w"): ("layers", "wo"),
+    ("dense_layers", "ffn", "w1"): ("layers", "w1"),
+    ("dense_layers", "ffn", "w3"): ("layers", "w3"),
+    ("dense_layers", "ffn", "w2"): ("layers", "w2"),
+}
+NORM_SCALES = {("final_norm", "scale"), ("dense_layers", "ln_attn", "scale"),
+               ("dense_layers", "ln_ffn", "scale")}
+
+
+def placement(dec: dict):
+    """Where the program keeps each drawn weight (``bench/system.py``
+    ``place``): the same map for every configuration of this family."""
+    return PROGRAM_LEAVES, NORM_SCALES
 
 
 def _served_dtype(dec):

@@ -115,19 +115,16 @@ def check_sample(cell, window, seed):
 def compare(cell, seed, sids, window, sample):
     """The compared numbers of bench/check.py (module docstring), and the
     reference's log-probs over the sample."""
-    import importlib
-
     import numpy as np
 
-    from bench import check
+    from bench import check, system
 
     cs = check.ConstraintSet(sids)
     served = [r for r in window.records if r.ok]
     numbers = {"violations": float(check.violations(
         cs, np.stack([r.sids for r in served]),
         np.stack([r.scores for r in served])))}
-    ref = importlib.import_module(
-        f"bench.references.{cell.config['reference']}")
+    ref = system.reference(cell.config)
     prompts = np.stack([r.prompt for r in sample])
     beams = np.stack([r.sids for r in sample]).astype(np.int64)
     scores = np.stack([r.scores for r in sample])

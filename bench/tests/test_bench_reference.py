@@ -61,19 +61,20 @@ def test_a_changed_program_layout_fails_loudly():
     cfg = _tiny.CONFIG
     spec = transformer.param_specs(system.program_config(cfg))
     w = dense_gqa.weights_from_key(cfg["decoder"], jax.random.key(SEED))
+    leaves, ones = dense_gqa.placement(cfg["decoder"])
     extra = dict(spec, unemb=jax.ShapeDtypeStruct((64, 34), "bfloat16"))
     with pytest.raises(KeyError, match="unemb"):
-        system.place(extra, w)
+        system.place(extra, w, leaves, ones)
     short = dict(spec)
     del short["emb"]
     with pytest.raises(KeyError, match="emb"):
-        system.place(short, w)
+        system.place(short, w, leaves, ones)
     wq = spec["dense_layers"]["attn"]["wq"]["w"]
     widened = jax.tree.map(lambda s: s, spec)
     widened["dense_layers"]["attn"]["wq"]["w"] = jax.ShapeDtypeStruct(
         wq.shape, "float32")
     with pytest.raises(ValueError, match="wq"):
-        system.place(widened, w)
+        system.place(widened, w, leaves, ones)
 
 
 def test_reference_is_the_program_at_float32():

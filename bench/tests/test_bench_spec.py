@@ -33,10 +33,15 @@ def test_config_is_the_programs(cfg):
     c = json.loads((ROOT / cfg["file"]).read_text())
     pcfg = system.program_config(c)
     for k, v in c["decoder"].items():
-        if k != "base":
+        if isinstance(v, dict):
+            for kk, vv in v.items():
+                assert getattr(getattr(pcfg, k), kk) == vv, f"{k}.{kk}"
+        elif k != "base":
             assert getattr(pcfg, k) == v, k
-    assert pcfg.resolved_head_dim() == c["decoder"]["head_dim"]
+    if "head_dim" in c["decoder"]:
+        assert pcfg.resolved_head_dim() == c["decoder"]["head_dim"]
     assert (spec.BENCH / "references" / f"{c['reference']}.py").exists()
+    assert (spec.BENCH / "counts" / f"{c['reference']}.py").exists()
     assert set(cfg["reduced"]) <= set(c) | set(c["decoder"])
     assert set(c["limits"]) == {"violations", "score_gap", "select_gap"}
     assert c["limits"]["violations"] == 0

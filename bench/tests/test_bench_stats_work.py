@@ -102,6 +102,27 @@ def test_work_counts_match_the_hand_counts():
     assert v.flops() > r.flops()
 
 
+def test_prod_counts_are_the_exact_integers():
+    """The counts of ``static-gr-3b.prod``, pinned: a change of the
+    yardstick shows here before it shows in a metric."""
+    r = work.retrieval(PROD)
+    assert r.dec.params == 3_605_173_248
+    assert r.dec.weight_bytes == 7_210_346_496
+    assert r.dec.kv_bytes_per_token == 106_496
+    assert r.history_kv_bytes() == 27_262_976
+    assert r.prefill_flops() == 1_853_063_430_144
+    assert r.flops() == 5_426_670_403_584
+    assert r.level_bytes(1, 2) == 7_279_781_888
+    assert r.prefill_bytes(2) == 7_264_872_448
+    assert r.decoder_least_seconds(2, 197e12, 819e9) == 0.0814154780523969
+
+
+def test_dense_weights_are_read_whole_whatever_the_rows():
+    d = work.retrieval(PROD).dec
+    assert {d.weight_bytes_read(n) for n in (1, 140, 512)} == {
+        d.weight_bytes}
+
+
 def test_least_time_is_the_larger_bound_of_each_pass():
     r = work.retrieval(PROD)
     t = r.decoder_least_seconds(2, 197e12, 819e9)
