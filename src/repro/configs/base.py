@@ -9,6 +9,7 @@ used by CPU smoke tests; the full config is exercised only via the dry-run
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional
 
 # --------------------------------------------------------------------------
@@ -18,9 +19,20 @@ from typing import Optional
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    """An expert layer.  The router scores ``n_routed`` experts and keeps
+    ``top_k`` of them per token; this chip holds ``n_experts`` of those,
+    ids ``expert_offset .. expert_offset + n_experts - 1``, and computes
+    only their part of the result (expert parallelism without the
+    exchange: the other chips' experts add the rest)."""
+
+    n_experts: int  # experts held here
     top_k: int
     d_expert: int  # expert FFN hidden width
+    n_routed: int = 0  # experts the router scores (0 => n_experts)
+    expert_offset: int = 0  # id of the first expert held here
+    # top-k weights renormalised to sum to one (Mixtral) or kept as the
+    # softmax gives them (DeepSeek-V2)
+    norm_topk_prob: bool = True
     n_shared: int = 0  # shared (always-on) experts
     d_shared: int = 0  # shared-expert hidden width (n_shared * d_expert if 0)
     first_dense_layers: int = 0  # leading layers that use a dense FFN
@@ -32,6 +44,28 @@ class MoEConfig:
     # cross-shard prefix sums). g >= 1 splits (B, S) into B*g groups so the
     # cumsum/scatter stay shard-local (EXPERIMENTS.md §Perf hillclimb B).
     dispatch_groups: int = 0
+
+    @property
+    def router_width(self) -> int:
+        return self.n_routed or self.n_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling (arXiv 2309.00071), as DeepSeek-V2 configures
+    it (``rope_scaling`` with ``type: yarn``)."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """DeepSeek-V2's ``yarn_get_mscale``: 0.1 * mscale * ln(factor) + 1."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,6 +90,7 @@ class TransformerConfig:
     moe: Optional[MoEConfig] = None
     # --- misc ---
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YarnScaling] = None  # MLA's rotary dims only
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
@@ -103,6 +138,15 @@ class TransformerConfig:
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
 
+    def mla_softmax_scale(self) -> float:
+        """DeepSeek-V2's attention scale: (nope + rope)^-1/2, times
+        ``yarn_mscale(factor, mscale_all_dim)`` squared under YaRN."""
+        scale = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        y = self.rope_scaling
+        if y is not None and y.mscale_all_dim:
+            scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+        return scale
+
     def param_count(self) -> int:
         """Approximate parameter count (embeddings + layers)."""
         D, V, L = self.d_model, self.vocab_size, self.n_layers
@@ -124,7 +168,7 @@ class TransformerConfig:
             layers = L * (attn + ffn)
         else:
             m = self.moe
-            moe_ffn = 3 * D * m.d_expert * m.n_experts + D * m.n_experts
+            moe_ffn = 3 * D * m.d_expert * m.n_experts + D * m.router_width
             shared = 3 * D * (m.d_shared or m.n_shared * m.d_expert) if m.n_shared else 0
             dense = 3 * D * (m.d_ff_dense or self.d_ff)
             layers = (

@@ -18,12 +18,14 @@ pure shape plumbing; ownership and refcounts are host state
 from __future__ import annotations
 
 import dataclasses
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 __all__ = [
-    "KVCache", "MLACache", "SharedHistoryCache", "init_kv_cache",
+    "KVCache", "MLACache", "SharedHistoryCache", "LatentHistoryCache",
+    "init_kv_cache",
     "init_mla_cache",
     "init_page_pool", "scatter_pages", "gather_pages", "pages_for",
 ]
@@ -62,6 +64,34 @@ class SharedHistoryCache:
     sfx_k: jax.Array  # (L, B*M, Ls, KVH, Dh), or (L, B, M, Ls, KVH, Dh)
     sfx_v: jax.Array
     step: jax.Array  # () suffix column of the next token
+    # MoE decoders: rows each held expert received
+    expert_rows: Optional[jax.Array] = None
+
+    def take_beams(self, rows: jax.Array):
+        """The cache with the flat suffix's rows ``rows`` (B*M,) kept."""
+        return dataclasses.replace(
+            self, sfx_k=jnp.take(self.sfx_k, rows, axis=1),
+            sfx_v=jnp.take(self.sfx_v, rows, axis=1))
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class LatentHistoryCache:
+    """:class:`SharedHistoryCache` for latent attention (MLA): each
+    request's history held once as the latents prefill cached
+    (:class:`MLACache`), and each beam's SID suffix as latents."""
+
+    hist_c: jax.Array  # (L, B, S, kv_lora) normalised latents
+    hist_r: jax.Array  # (L, B, S, rope_dim) rotated keys
+    sfx_c: jax.Array  # (L, B*M, Ls, kv_lora), or (L, B, M, Ls, kv_lora)
+    sfx_r: jax.Array  # (L, B*M, Ls, rope_dim), or batched
+    step: jax.Array  # () suffix column of the next token
+    expert_rows: Optional[jax.Array] = None
+
+    def take_beams(self, rows: jax.Array):
+        return dataclasses.replace(
+            self, sfx_c=jnp.take(self.sfx_c, rows, axis=1),
+            sfx_r=jnp.take(self.sfx_r, rows, axis=1))
 
 
 def init_kv_cache(

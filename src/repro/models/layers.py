@@ -6,8 +6,12 @@ explicit PRNG key and return the param subtree.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+
+from repro.configs.base import YarnScaling, yarn_mscale
 
 __all__ = [
     "dense_init", "dense", "rms_norm_init", "rms_norm", "mlp_init", "mlp",
@@ -81,14 +85,44 @@ def swiglu(p, x):
 # --------------------------------------------------------------------------
 
 
-def rope_frequencies(head_dim: int, theta: float = 10_000.0) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def rope_frequencies(head_dim: int, theta: float = 10_000.0,
+                     scaling: YarnScaling | None = None) -> jax.Array:
+    """Inverse frequencies of the ``head_dim / 2`` rotary pairs; with
+    ``scaling``, YaRN's blend as DeepSeek-V2 computes it
+    (``DeepseekV2YarnRotaryEmbedding``): the fastest pairs keep their
+    frequency, the slowest are divided by ``factor``, and a linear ramp
+    between the correction dims ``beta_fast`` and ``beta_slow`` mixes the
+    two."""
+    base = theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+    if scaling is None:
+        return 1.0 / base
+
+    def correction_dim(rotations):
+        return (head_dim * math.log(
+            scaling.original_max_position_embeddings
+            / (rotations * 2 * math.pi))) / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(scaling.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    extrapolate = 1.0 - ramp
+    return ((1.0 / (scaling.factor * base)) * (1.0 - extrapolate)
+            + (1.0 / base) * extrapolate)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10_000.0):
-    """x: (..., S, H, Dh) or (..., S, Dh); positions: broadcastable to (..., S)."""
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10_000.0,
+               scaling: YarnScaling | None = None):
+    """x: (..., S, H, Dh) or (..., S, Dh); positions: broadcastable to (..., S).
+
+    Rotates the interleaved pairs ``(2i, 2i+1)`` by frequency ``i``.  With
+    YaRN ``scaling``, cos and sin are also scaled by
+    ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)``, as
+    DeepSeek-V2 does (1 where the two are equal)."""
     dh = x.shape[-1]
-    freqs = rope_frequencies(dh, theta)  # (Dh/2,)
+    freqs = rope_frequencies(dh, theta, scaling)  # (Dh/2,)
     # The barrier keeps XLA from constant-folding cos/sin when the positions
     # are static: folded values come from the compiler's host math, which
     # differs from the TPU's in the last place, so a decode at a static
@@ -99,6 +133,11 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10_000.0):
     if x.ndim == angles.ndim + 1:  # head axis present
         angles = angles[..., None, :]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = x[..., 0::2].astype(jnp.float32), x[..., 1::2].astype(jnp.float32)
     out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.reshape(x.shape).astype(x.dtype)

@@ -1,17 +1,25 @@
-"""Mixture-of-Experts FFN with scatter-based token dispatch.
+"""Mixture-of-Experts FFN: a router over every expert, and the part of the
+result that the experts held here give.
 
 Covers both assigned MoE archs:
-  * Mixtral-8x7B      — 8 experts, top-2, no shared experts.
-  * DeepSeek-V2-Lite  — 64 fine-grained routed experts top-6 + 2 shared
-                        experts, first layer dense.
+  * Mixtral-8x7B      — 8 experts, top-2 renormalised, no shared experts.
+  * DeepSeek-V2-Lite  — 64 fine-grained routed experts, softmax top-6 kept
+                        unnormalised, + 2 shared experts, first layer dense.
 
-Dispatch is position-in-expert scatter (not the GShard (T,E,C) one-hot
-einsum) so peak memory is O(E*C*D) for the expert buffer instead of
-O(T*E*C): positions are computed with a cumsum over the (T*k, E) assignment
-one-hot, tokens beyond the static capacity are dropped (capacity_factor
-1.25), and expert FFNs run as a single batched einsum over the (E, C, D)
-buffer.  Expert-parallel sharding partitions that leading E axis over the
-"model" mesh axis; XLA inserts the dispatch all-to-alls.
+The router keeps its full width (``MoEConfig.router_width``) and its top-k;
+the layer holds ``n_experts`` of the routed experts from ``expert_offset``
+and adds only their part (the rest lies on other chips), plus the shared
+expert.  Two dispatches:
+
+* :func:`moe_ffn` (training): position-in-expert scatter into a static
+  (E, C, D) buffer (not the GShard (T,E,C) one-hot einsum), so peak memory
+  is O(E*C*D); rows past the capacity (capacity_factor 1.25) are dropped.
+  Expert-parallel sharding partitions the leading E axis over the "model"
+  mesh axis; XLA inserts the dispatch all-to-alls.
+* :func:`moe_ffn_dropless` (serving): every (row, held expert) pair is
+  computed — the pairs are sorted by expert and run through grouped
+  matmuls (``jax.lax.ragged_dot``), so nothing is dropped and the work
+  follows the rows each expert really receives.
 """
 from __future__ import annotations
 
@@ -21,7 +29,8 @@ import jax.numpy as jnp
 from repro.configs.base import MoEConfig
 from repro.models.layers import _he, swiglu, swiglu_init
 
-__all__ = ["moe_init", "moe_ffn", "expert_capacity"]
+__all__ = ["moe_init", "moe_ffn", "moe_ffn_dropless", "route",
+           "expert_capacity"]
 
 
 def expert_capacity(n_tokens: int, cfg: MoEConfig) -> int:
@@ -32,7 +41,7 @@ def expert_capacity(n_tokens: int, cfg: MoEConfig) -> int:
 def moe_init(key, d_model: int, cfg: MoEConfig, dtype=jnp.bfloat16):
     k1, k2, k3, k4, k5 = jax.random.split(key, 5)
     p = {
-        "router": _he(k1, (d_model, cfg.n_experts), jnp.float32),
+        "router": _he(k1, (d_model, cfg.router_width), jnp.float32),
         "w1": _he(k2, (cfg.n_experts, d_model, cfg.d_expert), dtype),
         "w3": _he(k3, (cfg.n_experts, d_model, cfg.d_expert), dtype),
         "w2": _he(k4, (cfg.n_experts, cfg.d_expert, d_model), dtype,
@@ -72,27 +81,48 @@ def moe_ffn(params, x: jax.Array, cfg: MoEConfig):
     return out, aux
 
 
+def route(params, x: jax.Array, cfg: MoEConfig):
+    """x (T, D) -> (probs (T, router_width), weights (T, K), ids (T, K)).
+
+    Softmax over every routed expert in float32, greedy top-k; the top-k
+    weights are renormalised only where ``cfg.norm_topk_prob`` says so.
+    """
+    probs = jax.nn.softmax(x.astype(jnp.float32) @ params["router"], axis=-1)
+    top_w, top_i = jax.lax.top_k(probs, cfg.top_k)  # (T, K)
+    if cfg.norm_topk_prob:
+        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    return probs, top_w, top_i
+
+
+def _held(top_i: jax.Array, cfg: MoEConfig):
+    """Local ids (into the experts held here) and whether each is held."""
+    local = top_i - cfg.expert_offset
+    return local, (local >= 0) & (local < cfg.n_experts)
+
+
 def _moe_ffn_single(params, x: jax.Array, cfg: MoEConfig):
     T, D = x.shape
     E, K = cfg.n_experts, cfg.top_k
     C = expert_capacity(T, cfg)
 
-    router_logits = x.astype(jnp.float32) @ params["router"]  # (T, E)
-    probs = jax.nn.softmax(router_logits, axis=-1)
-    top_w, top_i = jax.lax.top_k(probs, K)  # (T, K)
-    top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    probs, top_w, top_i = route(params, x, cfg)
 
-    # Aux load-balancing loss (Switch-style): E * sum_e f_e * P_e.
-    me = jnp.mean(probs, axis=0)  # (E,)
-    assign = jax.nn.one_hot(top_i, E, dtype=jnp.float32)  # (T, K, E)
+    # Aux load-balancing loss (Switch-style): E * sum_e f_e * P_e, over
+    # every routed expert.
+    Er = cfg.router_width
+    me = jnp.mean(probs, axis=0)  # (Er,)
+    assign = jax.nn.one_hot(top_i, Er, dtype=jnp.float32)  # (T, K, Er)
     ce = jnp.mean(jnp.sum(assign, axis=1), axis=0) / K  # fraction per expert
-    aux_loss = cfg.router_aux_weight * E * jnp.sum(me * ce)
+    aux_loss = cfg.router_aux_weight * Er * jnp.sum(me * ce)
 
-    # Position-in-expert via cumsum over flattened (T*K) assignments.
-    flat_e = top_i.reshape(-1)  # (T*K,)
-    onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)  # (T*K, E)
+    # Position-in-expert via cumsum over flattened (T*K) assignments to the
+    # experts held here; the others' rows go to other chips.
+    local, held = _held(top_i, cfg)
+    flat_e = jnp.where(held, local, 0).reshape(-1)  # (T*K,)
+    onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32) \
+        * held.reshape(-1, 1)  # (T*K, E)
     pos = (jnp.cumsum(onehot, axis=0) * onehot).sum(-1) - 1  # (T*K,)
-    keep = pos < C
+    keep = (pos < C) & held.reshape(-1)
     slot = jnp.where(keep, pos, 0)
 
     token_idx = jnp.repeat(jnp.arange(T), K)
@@ -110,3 +140,42 @@ def _moe_ffn_single(params, x: jax.Array, cfg: MoEConfig):
     out_k = out_k * top_w.reshape(-1)[:, None].astype(x.dtype)
     out = jnp.zeros((T, D), x.dtype).at[token_idx].add(out_k)
     return out, aux_loss
+
+
+def moe_ffn_dropless(params, x: jax.Array, cfg: MoEConfig):
+    """x (..., D) -> (y (..., D), rows (n_experts,) int32): the served
+    expert layer, which computes every (row, held expert) pair.
+
+    The T*K routed pairs are sorted by held expert (pairs for experts held
+    elsewhere sort last and are left out) and run through three grouped
+    matmuls; each pair's output is weighted by its router weight and summed
+    back into its row.  ``rows[e]`` counts the pairs held expert ``e``
+    received.  Scopes under the caller's: ``moe_router``, ``moe_experts``
+    (dispatch, grouped matmuls, combine) and ``moe_shared``.
+    """
+    D = x.shape[-1]
+    flat = x.reshape(-1, D)
+    T, E, K = flat.shape[0], cfg.n_experts, cfg.top_k
+    with jax.named_scope("moe_router"):
+        _, top_w, top_i = route(params, flat, cfg)
+        local, held = _held(top_i, cfg)
+        key = jnp.where(held, local, E).reshape(-1)  # (T*K,) E = elsewhere
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.bincount(key, length=E + 1)[:E].astype(jnp.int32)
+    with jax.named_scope("moe_experts"):
+        xs = jnp.take(flat, order // K, axis=0)  # (T*K, D), by expert
+        h = jax.nn.silu(jax.lax.ragged_dot(xs, params["w1"], sizes)) \
+            * jax.lax.ragged_dot(xs, params["w3"], sizes)
+        y = jax.lax.ragged_dot(h, params["w2"], sizes)  # (T*K, D)
+        # rows past the held groups are not computed: zero them
+        computed = jnp.take(key, order) < E
+        y = jnp.where(computed[:, None], y, 0)
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(T * K, dtype=order.dtype))
+        y = jnp.take(y, inv, axis=0).reshape(T, K, D)
+        w = jnp.where(held, top_w, 0.0).astype(y.dtype)
+        out = jnp.einsum("tkd,tk->td", y, w)
+    if "shared" in params:
+        with jax.named_scope("moe_shared"):
+            out = out + swiglu(params["shared"], flat)
+    return out.reshape(x.shape), sizes

@@ -40,7 +40,7 @@ from repro.models.layers import (
     swiglu_init,
     _he,
 )
-from repro.models.moe import moe_ffn, moe_init
+from repro.models.moe import moe_ffn, moe_ffn_dropless, moe_init
 
 __all__ = [
     "init_params", "param_specs", "forward", "lm_loss",
@@ -149,27 +149,32 @@ def _attn_full(p, x, cfg: TransformerConfig, q_offset=0):
     if cfg.attention == "mla":
         nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
         H, lora, vd = cfg.n_heads, cfg.kv_lora_rank, cfg.v_head_dim
-        q = (x @ p["wq"]).reshape(B, S, H, nope + rope)
-        q_nope, q_rope = q[..., :nope], q[..., nope:]
-        kv_a = x @ p["w_kv_a"]  # (B, S, lora + rope)
-        c_kv = rms_norm(p["kv_norm"], kv_a[..., :lora])
-        k_rope = kv_a[..., lora:][:, :, None, :]  # (B, S, 1, rope)
-        pos = q_offset + jnp.arange(S)
-        q_rope = apply_rope(q_rope, pos[None], cfg.rope_theta)
-        k_rope = apply_rope(k_rope, pos[None], cfg.rope_theta)
-        kv_b = (c_kv @ p["w_kv_b"]).reshape(B, S, H, nope + vd)
-        k_nope, v = kv_b[..., :nope], kv_b[..., nope:]
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_rope, (B, S, H, rope))], axis=-1
-        )
-        q = jnp.concatenate([q_nope, q_rope], axis=-1)
-        out = chunked_causal_attention(
-            q, k, v, chunk_q=cfg.attn_chunk_q, chunk_kv=cfg.attn_chunk_kv,
-            window=cfg.sliding_window, q_offset=q_offset,
-            unroll=cfg.inner_unroll,
-        )
-        cache_kv = (c_kv, k_rope[:, :, 0, :])
-        return out.reshape(B, S, H * vd) @ p["wo"], cache_kv
+        with jax.named_scope("qkv_proj"):
+            q = (x @ p["wq"]).reshape(B, S, H, nope + rope)
+            q_nope, q_rope = q[..., :nope], q[..., nope:]
+            kv_a = x @ p["w_kv_a"]  # (B, S, lora + rope)
+            c_kv = rms_norm(p["kv_norm"], kv_a[..., :lora], cfg.norm_eps)
+            k_rope = kv_a[..., lora:][:, :, None, :]  # (B, S, 1, rope)
+            pos = q_offset + jnp.arange(S)
+            q_rope = apply_rope(q_rope, pos[None], cfg.rope_theta,
+                                cfg.rope_scaling)
+            k_rope = apply_rope(k_rope, pos[None], cfg.rope_theta,
+                                cfg.rope_scaling)
+            kv_b = (c_kv @ p["w_kv_b"]).reshape(B, S, H, nope + vd)
+            k_nope, v = kv_b[..., :nope], kv_b[..., nope:]
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(k_rope, (B, S, H, rope))], axis=-1
+            )
+            q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        with jax.named_scope("attention"):
+            out = chunked_causal_attention(
+                q, k, v, chunk_q=cfg.attn_chunk_q, chunk_kv=cfg.attn_chunk_kv,
+                window=cfg.sliding_window, q_offset=q_offset,
+                scale=cfg.mla_softmax_scale(), unroll=cfg.inner_unroll,
+            )
+        with jax.named_scope("out_proj"):
+            cache_kv = (c_kv, k_rope[:, :, 0, :])
+            return out.reshape(B, S, H * vd) @ p["wo"], cache_kv
     hd = cfg.resolved_head_dim()
     H, KV = cfg.n_heads, cfg.n_kv_heads
 
@@ -196,18 +201,22 @@ def _attn_full(p, x, cfg: TransformerConfig, q_offset=0):
         return out.reshape(B, S, H * hd) @ p["wo"]["w"], (k, v)
 
 
-def _layer_fwd(p, x, cfg: TransformerConfig, moe_layer: bool, q_offset=0):
+def _layer_fwd(p, x, cfg: TransformerConfig, moe_layer: bool, q_offset=0,
+               dropless: bool = False):
     attn_out, cache_kv = _attn_with_norm(p, x, cfg, q_offset)
     with jax.named_scope("out_proj"):
         x = x + attn_out
     with jax.named_scope("ffn"):
         h = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
-        if moe_layer:
+        aux = jnp.zeros((), jnp.float32)
+        if moe_layer and dropless:
+            y, _ = moe_ffn_dropless(p["moe"], h, cfg.moe)
+        elif moe_layer:
             y, aux = moe_ffn(p["moe"], h, cfg.moe)
-            x = x + y
-            return x, cache_kv, aux
-        x = x + swiglu(p["ffn"], h)
-    return x, cache_kv, jnp.zeros((), jnp.float32)
+        else:
+            y = swiglu(p["ffn"], h)
+        x = x + y
+    return x, cache_kv, aux
 
 
 def _attn_with_norm(p, x, cfg, q_offset):
@@ -234,14 +243,20 @@ def _sp_constraint(x, cfg: TransformerConfig):
 
 def forward(params, tokens: jax.Array, cfg: TransformerConfig,
             collect_cache: bool = False):
-    """tokens (B, S) -> hidden (B, S, D) [+ per-layer cache stacks, aux loss]."""
+    """tokens (B, S) -> hidden (B, S, D) [+ per-layer cache stacks, aux loss].
+
+    With ``collect_cache`` (the serving prefill) expert layers compute
+    every routed row (:func:`~repro.models.moe.moe_ffn_dropless`); training
+    keeps the capacity-bounded dispatch.
+    """
     x = jnp.take(params["emb"], tokens, axis=0)
     aux_total = jnp.zeros((), jnp.float32)
 
     def make_body(moe_layer: bool):
         def body(x, p):
             x = _sp_constraint(x, cfg)
-            y, cache_kv, aux = _layer_fwd(p, x, cfg, moe_layer)
+            y, cache_kv, aux = _layer_fwd(p, x, cfg, moe_layer,
+                                          dropless=collect_cache)
             y = _sp_constraint(y, cfg)
             ys = cache_kv if collect_cache else None
             return y, (ys, aux)
@@ -506,12 +521,15 @@ def _decode_attn_mla(p, x, cfg, c_kv_cache, k_rope_cache, slot_pos, pos):
     B = x.shape[0]
     nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     H, lora, vd = cfg.n_heads, cfg.kv_lora_rank, cfg.v_head_dim
+    scale = cfg.mla_softmax_scale()
     q = (x @ p["wq"]).reshape(B, 1, H, nope + rope)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = apply_rope(q_rope, pos[None, None], cfg.rope_theta)
+    q_rope = apply_rope(q_rope, pos[None, None], cfg.rope_theta,
+                        cfg.rope_scaling)
     kv_a = x @ p["w_kv_a"]
-    c_new = rms_norm(p["kv_norm"], kv_a[..., :lora])  # (B, 1, lora)
-    kr_new = apply_rope(kv_a[..., lora:], pos[None, None], cfg.rope_theta)
+    c_new = rms_norm(p["kv_norm"], kv_a[..., :lora], cfg.norm_eps)
+    kr_new = apply_rope(kv_a[..., lora:], pos[None, None], cfg.rope_theta,
+                        cfg.rope_scaling)
     if not cfg.defer_cache_write:
         slots = c_kv_cache.shape[1]
         slot = jnp.minimum(pos, slots - 1)
@@ -526,7 +544,7 @@ def _decode_attn_mla(p, x, cfg, c_kv_cache, k_rope_cache, slot_pos, pos):
                    c_kv_cache.astype(jnp.float32))
         + jnp.einsum("bqhr,bsr->bhqs", q_rope.astype(jnp.float32),
                      k_rope_cache.astype(jnp.float32))
-    ) * ((nope + rope) ** -0.5)
+    ) * scale
     mask = (slot_pos >= 0) & (
         (slot_pos < pos) if cfg.defer_cache_write else (slot_pos <= pos)
     )
@@ -538,7 +556,7 @@ def _decode_attn_mla(p, x, cfg, c_kv_cache, k_rope_cache, slot_pos, pos):
                        c_new.astype(jnp.float32))
             + jnp.einsum("bqhr,bqr->bhq", q_rope.astype(jnp.float32),
                          kr_new.astype(jnp.float32))
-        )[..., None] * ((nope + rope) ** -0.5)
+        )[..., None] * scale
         probs = jax.nn.softmax(jnp.concatenate([s, s_n], -1), axis=-1)
         ctx = jnp.einsum("bhqs,bsl->bqhl", probs[..., :-1],
                          c_kv_cache.astype(jnp.float32))
@@ -580,6 +598,101 @@ def _shared_history_attention(q, hist_k, hist_v, sfx_k, sfx_v, sfx_valid):
     return out.reshape(B, M, H, hd).astype(q.dtype)
 
 
+def _latent_attention(q_lat, q_rope, hist_c, hist_r, sfx_c, sfx_r,
+                      sfx_valid, scale):
+    """:func:`_shared_history_attention` for latent attention, absorbed:
+    the query's no-rope part, already through ``W_uk`` (``q_lat``), scores
+    the latents and its rope part the rotated keys, over the row's history
+    and the beam's suffix under one softmax; the context stays a latent.
+
+    q_lat (B, M, H, lora), q_rope (B, M, H, rope); hist_c/r (B, S, lora |
+    rope); sfx_c/r (B, M, Ls, lora | rope); sfx_valid (B, Ls).  Returns
+    the latent context (B, M, H, lora) in q_lat's dtype.
+    """
+    S = hist_c.shape[1]
+    f32 = jnp.float32
+    s_h = (jnp.einsum("bmhl,bsl->bmhs", q_lat, hist_c,
+                      preferred_element_type=f32)
+           + jnp.einsum("bmhr,bsr->bmhs", q_rope, hist_r,
+                        preferred_element_type=f32)) * scale
+    s_s = (jnp.einsum("bmhl,bmtl->bmht", q_lat, sfx_c,
+                      preferred_element_type=f32)
+           + jnp.einsum("bmhr,bmtr->bmht", q_rope, sfx_r,
+                        preferred_element_type=f32)) * scale
+    s_s = jnp.where(sfx_valid[:, None, None, :], s_s, NEG)
+    p = jax.nn.softmax(jnp.concatenate([s_h, s_s], axis=-1), axis=-1)
+    p = p.astype(hist_c.dtype)
+    ctx = jnp.einsum("bmhs,bsl->bmhl", p[..., :S], hist_c,
+                     preferred_element_type=f32) + jnp.einsum(
+        "bmht,bmtl->bmhl", p[..., S:], sfx_c, preferred_element_type=f32)
+    return ctx.astype(q_lat.dtype)
+
+
+def _gr_attn_gqa(a, h, hk, hv, bk, bv, i, pos, sid_step, sfx_valid, cfg):
+    """GQA for :func:`gr_decode_step`: writes this token's K/V into layer
+    ``i``'s suffix column ``sid_step`` and attends history and suffix."""
+    BM, B = h.shape[0], hk.shape[0]
+    M = BM // B
+    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim()
+    with jax.named_scope("qkv_proj"):
+        def proj(pp, width):
+            y = h @ pp["w"]
+            if "b" in pp:
+                y = y + pp["b"]
+            return y.reshape(BM, 1, width, hd)
+
+        q = apply_rope(proj(a["wq"], H), pos[None, None], cfg.rope_theta)
+        k_new = apply_rope(proj(a["wk"], KV), pos[None, None],
+                           cfg.rope_theta)
+        v_new = proj(a["wv"], KV)
+    with jax.named_scope("kv_write"):
+        at = (i, 0, 0, sid_step, 0, 0)
+        bk = jax.lax.dynamic_update_slice(
+            bk, k_new.reshape(1, B, M, 1, KV, hd).astype(bk.dtype), at)
+        bv = jax.lax.dynamic_update_slice(
+            bv, v_new.reshape(1, B, M, 1, KV, hd).astype(bv.dtype), at)
+    with jax.named_scope("attention"):
+        out = _shared_history_attention(
+            q.reshape(B, M, H, hd), hk, hv, bk[i], bv[i], sfx_valid)
+    with jax.named_scope("out_proj"):
+        return out.reshape(BM, 1, H * hd) @ a["wo"]["w"], bk, bv
+
+
+def _gr_attn_mla(a, h, hc, hr, sc, sr, i, pos, sid_step, sfx_valid, cfg):
+    """Latent attention for :func:`gr_decode_step`, absorbed: this token's
+    latent and rotated key go into layer ``i``'s suffix column
+    ``sid_step``; ``W_uk`` folds into the query and ``W_uv`` into the
+    output, so no K or V is ever expanded."""
+    BM, B = h.shape[0], hc.shape[0]
+    M = BM // B
+    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    H, lora, vd = cfg.n_heads, cfg.kv_lora_rank, cfg.v_head_dim
+    w_kv_b = a["w_kv_b"].reshape(lora, H, nope + vd)
+    with jax.named_scope("qkv_proj"):
+        q = (h @ a["wq"]).reshape(BM, 1, H, nope + rope)
+        q_rope = apply_rope(q[..., nope:], pos[None, None], cfg.rope_theta,
+                            cfg.rope_scaling)
+        kv_a = h @ a["w_kv_a"]  # (BM, 1, lora + rope)
+        c_new = rms_norm(a["kv_norm"], kv_a[..., :lora], cfg.norm_eps)
+        r_new = apply_rope(kv_a[..., lora:], pos[None, None], cfg.rope_theta,
+                           cfg.rope_scaling)
+        q_lat = jnp.einsum("bqhn,lhn->bqhl", q[..., :nope],
+                           w_kv_b[..., :nope])  # (BM, 1, H, lora)
+    with jax.named_scope("kv_write"):
+        at = (i, 0, 0, sid_step, 0)
+        sc = jax.lax.dynamic_update_slice(
+            sc, c_new.reshape(1, B, M, 1, lora).astype(sc.dtype), at)
+        sr = jax.lax.dynamic_update_slice(
+            sr, r_new.reshape(1, B, M, 1, rope).astype(sr.dtype), at)
+    with jax.named_scope("attention"):
+        ctx = _latent_attention(
+            q_lat.reshape(B, M, H, lora), q_rope.reshape(B, M, H, rope),
+            hc, hr, sc[i], sr[i], sfx_valid, cfg.mla_softmax_scale())
+    with jax.named_scope("out_proj"):
+        out = jnp.einsum("bmhl,lhv->bmhv", ctx, w_kv_b[..., nope:])
+        return out.reshape(BM, 1, H * vd) @ a["wo"], sc, sr
+
+
 def gr_decode_step(
     params,
     hist_k: jax.Array,  # (n_layers, B, S, KV, hd) history, one per request
@@ -593,94 +706,122 @@ def gr_decode_step(
     """One decode level of generative retrieval: the batch and SPMD
     retrievers' served step (DESIGN.md §14).
 
-    Each request's history K/V is held once and shared by its M beams;
-    only the SID suffix is beam-private, in the flat ``(n_layers, B*M, ...)``
-    or the batched ``(n_layers, B, M, ...)`` layout, whichever the caller
+    Each request's history is held once and shared by its M beams; only
+    the SID suffix is beam-private, in the flat ``(n_layers, B*M, ...)`` or
+    the batched ``(n_layers, B, M, ...)`` layout, whichever the caller
     keeps.  The token at ``sid_step`` sits at position ``S + sid_step``, its
-    K/V goes into suffix column ``sid_step``, and every beam attends its
+    state goes into suffix column ``sid_step``, and every beam attends its
     whole history and suffix columns ``0..sid_step`` under one softmax.
-    Dense-FFN GQA/MHA decoders only: no MLA, no MoE group, and no sliding
-    window shorter than ``S + Ls``.
+
+    The state is the decoder's: K and V for GQA/MHA; for latent attention
+    (MLA) the normalised latents (``hist_k``/``beam_k``, last axis
+    ``kv_lora_rank``) and the rotated keys (``hist_v``/``beam_v``, last
+    axis ``qk_rope_head_dim``), attended absorbed.  The layers run stack by
+    stack (``dense_layers``, then ``moe_layers``), each keeping its global
+    index for its suffix slot; expert layers compute every routed row
+    (:func:`~repro.models.moe.moe_ffn_dropless`).  No sliding window
+    shorter than ``S + Ls``.
 
     The pieces carry :func:`decode_step`'s ``jax.named_scope`` names
     (``embed``, ``qkv_proj``, ``kv_write``, ``attention``, ``out_proj``,
-    ``ffn``, ``unembed``; DESIGN.md §9).
+    ``ffn``, ``unembed``; DESIGN.md §9); expert layers add ``moe_router``,
+    ``moe_experts`` and ``moe_shared`` under ``ffn``.
 
     Returns ``(logits (B*M, 1, vocab), new beam_k, new beam_v)``.
     """
+    return _gr_decode(params, hist_k, hist_v, beam_k, beam_v, tokens,
+                      sid_step, cfg)[:3]
+
+
+def _gr_decode(params, hist_a, hist_b, beam_a, beam_b, tokens, sid_step,
+               cfg):
+    """:func:`gr_decode_step`, and the rows each held expert received
+    (``moe_ffn_dropless``'s count, summed over the expert layers), or
+    None for a decoder without experts."""
     BM = tokens.shape[0]
-    n, B, S = hist_k.shape[:3]
+    n, B, S = hist_a.shape[:3]
     M = BM // B
-    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim()
-    Ls = beam_k.shape[-3]
+    Ls = beam_a.shape[2 if beam_a.ndim == hist_a.ndim else 3]
     pos = S + sid_step
     sfx_valid = jnp.broadcast_to(jnp.arange(Ls) <= sid_step, (B, Ls))
+    attend = _gr_attn_mla if cfg.attention == "mla" else _gr_attn_gqa
     with jax.named_scope("embed"):
         x = jnp.take(params["emb"], tokens, axis=0)  # (BM, 1, D)
 
     def body(carry, inp):
-        x, bk, bv = carry
-        p, hk, hv, i = inp
-        a = p["attn"]
+        x, sa, sb, rows = carry
+        p, ha, hb, i = inp
         with jax.named_scope("qkv_proj"):
             h = rms_norm(p["ln_attn"], x, cfg.norm_eps)
-
-            def proj(pp, width):
-                y = h @ pp["w"]
-                if "b" in pp:
-                    y = y + pp["b"]
-                return y.reshape(BM, 1, width, hd)
-
-            q = apply_rope(proj(a["wq"], H), pos[None, None], cfg.rope_theta)
-            k_new = apply_rope(proj(a["wk"], KV), pos[None, None],
-                               cfg.rope_theta)
-            v_new = proj(a["wv"], KV)
-        with jax.named_scope("kv_write"):
-            at = (i, 0, 0, sid_step, 0, 0)
-            bk = jax.lax.dynamic_update_slice(
-                bk, k_new.reshape(1, B, M, 1, KV, hd).astype(bk.dtype), at)
-            bv = jax.lax.dynamic_update_slice(
-                bv, v_new.reshape(1, B, M, 1, KV, hd).astype(bv.dtype), at)
-        with jax.named_scope("attention"):
-            out = _shared_history_attention(
-                q.reshape(B, M, H, hd), hk, hv, bk[i], bv[i], sfx_valid)
+        out, sa, sb = attend(p["attn"], h, ha, hb, sa, sb, i, pos, sid_step,
+                             sfx_valid, cfg)
         with jax.named_scope("out_proj"):
-            x = x + out.reshape(BM, 1, H * hd) @ a["wo"]["w"]
+            x = x + out
         with jax.named_scope("ffn"):
-            x = x + swiglu(p["ffn"], rms_norm(p["ln_ffn"], x, cfg.norm_eps))
-        return (x, bk, bv), None
+            h = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
+            if "moe" in p:
+                y, r = moe_ffn_dropless(p["moe"], h, cfg.moe)
+                rows = rows + r
+            else:
+                y = swiglu(p["ffn"], h)
+            x = x + y
+        return (x, sa, sb, rows), None
 
     # The suffixes ride in the carry, each layer's slot written in place:
     # as the scan's xs/ys every layer's slice would be sliced out and
-    # stacked back, a copy each way per layer and level.
-    batched = (n, B, M, Ls, KV, hd)
-    (x, new_bk, new_bv), _ = jax.lax.scan(
-        body, (x, beam_k.reshape(batched), beam_v.reshape(batched)),
-        (params["dense_layers"], hist_k, hist_v, jnp.arange(n)),
-        unroll=cfg.layer_unroll,
-    )
+    # stacked back, a copy each way per layer and level.  The histories
+    # are only read, as the scan's xs.
+    n_dense = cfg.moe.first_dense_layers if cfg.moe else n
+    rows = (jnp.zeros((cfg.moe.n_experts,), jnp.int32) if cfg.moe
+            else None)
+    carry = (x, beam_a.reshape((n, B, M, Ls) + hist_a.shape[3:]),
+             beam_b.reshape((n, B, M, Ls) + hist_b.shape[3:]), rows)
+    for group, lo, hi in (("dense_layers", 0, n_dense),
+                          ("moe_layers", n_dense, n)):
+        if hi > lo:
+            carry, _ = jax.lax.scan(
+                body, carry, (params[group], hist_a[lo:hi], hist_b[lo:hi],
+                              jnp.arange(lo, hi)),
+                unroll=cfg.layer_unroll)
+    x, new_a, new_b, rows = carry
     with jax.named_scope("unembed"):
         x = rms_norm(params["final_norm"], x, cfg.norm_eps)
         logits = (x @ _unemb(params, cfg)).astype(jnp.float32)  # (BM, 1, V)
-    return logits, new_bk.reshape(beam_k.shape), new_bv.reshape(beam_v.shape)
+    return (logits, new_a.reshape(beam_a.shape), new_b.reshape(beam_b.shape),
+            rows)
+
+
+def _shared_history_step(params, cache, tokens, cfg):
+    """:func:`decode_step` for the retrievers' shared-history caches."""
+    if isinstance(cache, kv_lib.LatentHistoryCache):
+        names = ("sfx_c", "sfx_r")
+        hist = (cache.hist_c, cache.hist_r)
+    else:
+        names = ("sfx_k", "sfx_v")
+        hist = (cache.hist_k, cache.hist_v)
+    logits, a, b, rows = _gr_decode(
+        params, *hist, *(getattr(cache, f) for f in names), tokens,
+        cache.step, cfg)
+    new = dict(zip(names, (a, b)), step=cache.step + 1)
+    if cache.expert_rows is not None:
+        new["expert_rows"] = cache.expert_rows + rows
+    return logits, dataclasses.replace(cache, **new)
 
 
 def decode_step(params, cache, tokens: jax.Array, cfg: TransformerConfig):
     """One autoregressive step. tokens (B, 1) -> (logits (B,1,V), new cache).
 
-    A :class:`~repro.models.kvcache.SharedHistoryCache` takes
+    A :class:`~repro.models.kvcache.SharedHistoryCache` or
+    :class:`~repro.models.kvcache.LatentHistoryCache` takes
     :func:`gr_decode_step`, with one token per beam (tokens (B*M, 1)).
 
     Each piece of the step has a ``jax.named_scope`` (``embed``,
     ``qkv_proj``, ``kv_write``, ``attention``, ``out_proj``, ``ffn``,
     ``unembed``; DESIGN.md §9), so a profile splits a decode level by them.
     """
-    if isinstance(cache, kv_lib.SharedHistoryCache):
-        logits, sfx_k, sfx_v = gr_decode_step(
-            params, cache.hist_k, cache.hist_v, cache.sfx_k, cache.sfx_v,
-            tokens, cache.step, cfg)
-        return logits, dataclasses.replace(
-            cache, sfx_k=sfx_k, sfx_v=sfx_v, step=cache.step + 1)
+    if isinstance(cache, (kv_lib.SharedHistoryCache,
+                          kv_lib.LatentHistoryCache)):
+        return _shared_history_step(params, cache, tokens, cfg)
     B = tokens.shape[0]
     with jax.named_scope("embed"):
         x = jnp.take(params["emb"], tokens, axis=0)  # (B, 1, D)
@@ -714,7 +855,7 @@ def decode_step(params, cache, tokens: jax.Array, cfg: TransformerConfig):
         with jax.named_scope("ffn"):
             h = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
             if "moe" in p:
-                y, _ = moe_ffn(p["moe"], h, cfg.moe)
+                y, _ = moe_ffn_dropless(p["moe"], h, cfg.moe)
                 x = x + y
             else:
                 x = x + swiglu(p["ffn"], h)

@@ -319,6 +319,17 @@ class _EngineMetrics:
             self.recompiles.inc(
                 compiles, expected="true" if expected else "false")
 
+    def record_experts(self, rows, offset: int) -> None:
+        """An expert decoder's batch: ``rows[e]`` rows routed to held
+        expert ``offset + e`` over the decode levels."""
+        r = self.registry
+        routed = r.counter(
+            "serving_moe_expert_rows_total",
+            "rows the decode levels routed to each expert held here, "
+            "summed over the expert layers, by expert id")
+        for e, n in enumerate(rows):
+            routed.inc(int(n), expert=str(offset + e))
+
     def record_request(self, r: Request, t_admit: float, t_done: float, *,
                        t_first: Optional[float] = None,
                        n_out: Optional[int] = None) -> dict:
@@ -501,6 +512,10 @@ class ServingEngine:
                     expected=cold or self._served_batches == 0,
                 )
                 self._served_batches += 1
+                rows = self.retriever.expert_rows
+                if rows is not None:
+                    self._m.record_experts(
+                        rows, self.retriever.cfg.moe.expert_offset)
                 for i, r in enumerate(batch):
                     results[r.rid] = {
                         "sids": beams[i],

@@ -2,8 +2,9 @@
 
 ``GenerativeRetriever.retrieve`` takes user-history token sequences, prefills
 the model once per request, then runs the constrained beam search of
-Algorithm 1 over SID tokens.  Each history's K/V is held once and shared by
-the request's beams; only the SID suffix is per beam (DESIGN.md §14).
+Algorithm 1 over SID tokens.  Each history's state (K/V, or the latents of
+latent attention) is held once and shared by the request's beams; only the
+SID suffix is per beam (DESIGN.md §14).
 Which constraint method masks each decode level is bound by a
 :class:`~repro.decoding.DecodePolicy` — the paper's STATIC matrix (100%
 compliance, §5.4), the stacked multi-tenant store, or any §5.2 baseline all
@@ -25,7 +26,6 @@ plumbing here and cannot flip across a hot-swap.
 """
 from __future__ import annotations
 
-import dataclasses
 from typing import Optional
 
 import jax
@@ -60,6 +60,9 @@ class GenerativeRetriever:
         self.L = sid_length
         self.V = sid_vocab
         self.M = beam_size
+        # expert decoders: the last batch's rows per held expert over its
+        # decode levels; None otherwise
+        self.expert_rows: Optional[np.ndarray] = None
         # One jitted end-to-end retrieval step (prefill + L constrained beam
         # steps).  The policy rides in as a pytree ARGUMENT, so a registry
         # hot-swap (new leaf values, identical shapes + static metadata)
@@ -116,10 +119,12 @@ class GenerativeRetriever:
                 )
             cids = jnp.asarray(cids_np)
         with annotate("retrieve.dispatch"):
-            tokens, scores = self._retrieve_jit(
+            tokens, scores, rows = self._retrieve_jit(
                 self.params, jnp.asarray(history), self.policy, cids
             )
         with annotate("retrieve.readback"):
+            if rows is not None:
+                self.expert_rows = np.asarray(rows)
             return np.asarray(tokens), np.asarray(scores)
 
     def compile_step(self, batch: int, prompt_width: int):
@@ -132,30 +137,46 @@ class GenerativeRetriever:
             self.params, hist, self.policy, None).compile()
 
     def _retrieve_impl(self, params, history, policy, constraint_ids):
+        """(tokens (B, M, L), scores (B, M), expert rows): the last is the
+        rows each held expert received over the decode levels, for an
+        expert decoder, else None."""
         B, S = history.shape
         M, cfg = self.M, self.cfg
         Ls = self.L - 1  # a suffix column for each decode level after prefill
         window = cfg.sliding_window
-        if (cfg.attention == "mla" or cfg.moe is not None
-                or (window is not None and window < S + Ls)):
+        if window is not None and window < S + Ls:
             raise NotImplementedError(
                 "the retrieval step shares each history across its beams "
-                "(transformer.gr_decode_step), which serves dense-FFN "
-                "GQA/MHA decoders whose window covers history and SID "
-                f"suffix ({S + Ls} positions); got attention="
-                f"{cfg.attention!r}, moe={cfg.moe is not None}, "
-                f"sliding_window={window}")
+                "(transformer.gr_decode_step), which serves decoders whose "
+                f"window covers history and SID suffix ({S + Ls} "
+                f"positions); got sliding_window={window}")
+        if not cfg.tie_embeddings:
+            # only the SID columns of an untied head are ever scored
+            params = dict(params, unemb=params["unemb"][:, : self.V])
         # named_scope: trace-time profiler labels only (DESIGN.md §9) —
         # no runtime cost, no change to the computation.
         with jax.named_scope("prefill"):
             pre_logits, cache = transformer.prefill(params, history, cfg)
-        # the history, (n_layers, B, S, KV, hd), once per request for its
-        # M beams; the suffix of each beam starts empty
-        suffix = jnp.zeros((cfg.n_layers, B * M, Ls) + cache.k.shape[3:],
-                           cache.k.dtype)
-        cache = kv_lib.SharedHistoryCache(
-            hist_k=cache.k, hist_v=cache.v, sfx_k=suffix, sfx_v=suffix,
-            step=jnp.zeros((), jnp.int32))
+        # the history, (n_layers, B, S, ...), once per request for its M
+        # beams; the suffix of each beam starts empty
+        def empty_suffix(a):
+            return jnp.zeros((cfg.n_layers, B * M, Ls) + a.shape[3:],
+                             a.dtype)
+
+        rows = (jnp.zeros((cfg.moe.n_experts,), jnp.int32)
+                if cfg.moe else None)
+        step = jnp.zeros((), jnp.int32)
+        if isinstance(cache, kv_lib.MLACache):
+            cache = kv_lib.LatentHistoryCache(
+                hist_c=cache.c_kv, hist_r=cache.k_rope,
+                sfx_c=empty_suffix(cache.c_kv),
+                sfx_r=empty_suffix(cache.k_rope), step=step,
+                expert_rows=rows)
+        else:
+            sfx = empty_suffix(cache.k)
+            cache = kv_lib.SharedHistoryCache(
+                hist_k=cache.k, hist_v=cache.v, sfx_k=sfx, sfx_v=sfx,
+                step=step, expert_rows=rows)
 
         def logits_fn(c, last_tokens, step):
             logits, c = transformer.decode_step(
@@ -163,15 +184,13 @@ class GenerativeRetriever:
             return logits[:, 0, : self.V].reshape(B, M, self.V), c
 
         def gather_suffix(c, beam_idx):
-            flat = (jnp.arange(B)[:, None] * M + beam_idx).reshape(-1)
-            return dataclasses.replace(
-                c, sfx_k=jnp.take(c.sfx_k, flat, axis=1),
-                sfx_v=jnp.take(c.sfx_v, flat, axis=1))
+            return c.take_beams((jnp.arange(B)[:, None] * M
+                                 + beam_idx).reshape(-1))
 
-        state, _ = beam_search(
+        state, cache = beam_search(
             logits_fn, cache, B, M, self.L, policy,
             carry_gather_fn=gather_suffix,
             first_logits=pre_logits[:, 0, : self.V],
             constraint_ids=constraint_ids,
         )
-        return state.tokens, state.scores
+        return state.tokens, state.scores, cache.expert_rows

@@ -120,7 +120,8 @@ class SpmdRetriever(GenerativeRetriever):
             if self.rows == "model":
                 policy = to_row_sharded(policy, n_shards=ms)
             ids = cids if policy.requires_constraint_ids else None
-            tokens, scores = self._retrieve_impl(params, history, policy, ids)
+            tokens, scores, _ = self._retrieve_impl(params, history, policy,
+                                                    ids)
             # inactive (padding / free-slot) rows: parked at NEG_INF so no
             # consumer can mistake them for results
             scores = jnp.where(active[:, None], scores, NEG_INF)
