@@ -15,6 +15,7 @@ import jax
 
 from repro.kernels import ref as _ref
 from repro.kernels.embedding_bag import embedding_bag_pallas
+from repro.kernels.grouped_matmul import grouped_matmul_pallas
 from repro.kernels.vntk import (
     vntk_compressed_pallas,
     vntk_compressed_topk_pallas,
@@ -29,10 +30,11 @@ from repro.kernels.vntk import (
 )
 
 __all__ = ["vntk", "vntk_fused_logsoftmax", "vntk_topk", "vntk_compressed",
-           "vntk_compressed_topk", "embedding_bag"]
+           "vntk_compressed_topk", "embedding_bag", "grouped_matmul",
+           "resolve"]
 
 
-def _resolve(impl: str | None) -> str:
+def resolve(impl: str | None) -> str:
     if impl is None:
         return "pallas" if jax.default_backend() == "tpu" else "xla"
     return impl
@@ -49,10 +51,10 @@ def vntk(log_probs, nodes, row_pointers, edges, bmax: int, vocab: int,
     path untouched (the branch is resolved at trace time).
     """
     if constraint_ids is None:
-        if _resolve(impl) == "pallas":
+        if resolve(impl) == "pallas":
             return vntk_pallas(log_probs, nodes, row_pointers, edges, bmax, vocab)
         return _ref.vntk_ref(log_probs, nodes, row_pointers, edges, bmax, vocab)
-    if _resolve(impl) == "pallas":
+    if resolve(impl) == "pallas":
         return vntk_stacked_pallas(
             log_probs, nodes, constraint_ids, row_pointers, edges, bmax, vocab
         )
@@ -67,14 +69,14 @@ def vntk_fused_logsoftmax(logits, nodes, row_pointers, edges, bmax: int,
                           constraint_ids=None):
     """Fused LogSoftmax + VNTK masking (single HBM pass over logits)."""
     if constraint_ids is None:
-        if _resolve(impl) == "pallas":
+        if resolve(impl) == "pallas":
             return vntk_fused_logsoftmax_pallas(
                 logits, nodes, row_pointers, edges, bmax, vocab
             )
         return _ref.vntk_fused_logsoftmax_ref(
             logits, nodes, row_pointers, edges, bmax, vocab
         )
-    if _resolve(impl) == "pallas":
+    if resolve(impl) == "pallas":
         return vntk_stacked_fused_logsoftmax_pallas(
             logits, nodes, constraint_ids, row_pointers, edges, bmax, vocab
         )
@@ -97,7 +99,7 @@ def vntk_topk(values, nodes, row_pointers, edges, bmax: int, vocab: int,
     ``constraint_ids`` the tables carry the stacked leading constraint axis.
     """
     if constraint_ids is None:
-        if _resolve(impl) == "pallas":
+        if resolve(impl) == "pallas":
             return vntk_topk_pallas(
                 values, nodes, row_pointers, edges, bmax, vocab, width,
                 fused_logsoftmax=fused_logsoftmax,
@@ -106,7 +108,7 @@ def vntk_topk(values, nodes, row_pointers, edges, bmax: int, vocab: int,
             values, nodes, row_pointers, edges, bmax, vocab, width,
             fused_logsoftmax=fused_logsoftmax,
         )
-    if _resolve(impl) == "pallas":
+    if resolve(impl) == "pallas":
         return vntk_stacked_topk_pallas(
             values, nodes, constraint_ids, row_pointers, edges, bmax, vocab,
             width, fused_logsoftmax=fused_logsoftmax,
@@ -131,7 +133,7 @@ def vntk_compressed(values, nodes, row_pointers, tok_delta, base, bmax: int,
     :func:`vntk_fused_logsoftmax` on the same trie.
     """
     if constraint_ids is None:
-        if _resolve(impl) == "pallas":
+        if resolve(impl) == "pallas":
             return vntk_compressed_pallas(
                 values, nodes, row_pointers, tok_delta, base, bmax, vocab,
                 fused_logsoftmax=fused_logsoftmax,
@@ -140,7 +142,7 @@ def vntk_compressed(values, nodes, row_pointers, tok_delta, base, bmax: int,
             values, nodes, row_pointers, tok_delta, base, bmax, vocab,
             fused_logsoftmax=fused_logsoftmax,
         )
-    if _resolve(impl) == "pallas":
+    if resolve(impl) == "pallas":
         return vntk_stacked_compressed_pallas(
             values, nodes, constraint_ids, row_pointers, tok_delta, base,
             bmax, vocab, fused_logsoftmax=fused_logsoftmax,
@@ -159,7 +161,7 @@ def vntk_compressed_topk(values, nodes, row_pointers, tok_delta, base,
                          fused_logsoftmax: bool = False):
     """Candidate-compressed VNTK over the compressed slab (§8 x §11)."""
     if constraint_ids is None:
-        if _resolve(impl) == "pallas":
+        if resolve(impl) == "pallas":
             return vntk_compressed_topk_pallas(
                 values, nodes, row_pointers, tok_delta, base, bmax, vocab,
                 width, fused_logsoftmax=fused_logsoftmax,
@@ -168,7 +170,7 @@ def vntk_compressed_topk(values, nodes, row_pointers, tok_delta, base,
             values, nodes, row_pointers, tok_delta, base, bmax, vocab, width,
             fused_logsoftmax=fused_logsoftmax,
         )
-    if _resolve(impl) == "pallas":
+    if resolve(impl) == "pallas":
         return vntk_stacked_compressed_topk_pallas(
             values, nodes, constraint_ids, row_pointers, tok_delta, base,
             bmax, vocab, width, fused_logsoftmax=fused_logsoftmax,
@@ -182,6 +184,21 @@ def vntk_compressed_topk(values, nodes, row_pointers, tok_delta, base,
 @partial(jax.jit, static_argnames=("mode", "impl"))
 def embedding_bag(table, indices, mode: str = "sum", impl: str | None = None):
     """Fixed-arity EmbeddingBag: (B, K) indices -> (B, D) reduced rows."""
-    if _resolve(impl) == "pallas":
+    if resolve(impl) == "pallas":
         return embedding_bag_pallas(table, indices, mode=mode)
     return _ref.embedding_bag_ref(table, indices, mode=mode)
+
+
+def grouped_matmul(lhs, rhs, sizes, impl: str | None = None):
+    """Rows of ``lhs`` (m, k) in groups of ``sizes`` (g,), laid end to end,
+    each times its ``rhs`` (g, k, n) slab: (m, n) in ``lhs.dtype``, with a
+    float32 accumulator.
+
+    "xla" is ``jax.lax.ragged_dot`` (rows past ``sum(sizes)`` read 0);
+    "pallas" the grouped-matmul kernel, whose ``m`` is a multiple of its
+    row tile (rows past ``sum(sizes)`` are left unwritten and may hold
+    anything).
+    """
+    if resolve(impl) == "pallas":
+        return grouped_matmul_pallas(lhs, rhs, sizes)
+    return jax.lax.ragged_dot(lhs, rhs, sizes)

@@ -64,8 +64,10 @@ class SharedHistoryCache:
     sfx_k: jax.Array  # (L, B*M, Ls, KVH, Dh), or (L, B, M, Ls, KVH, Dh)
     sfx_v: jax.Array
     step: jax.Array  # () suffix column of the next token
-    # MoE decoders: rows each held expert received
+    # MoE decoders: rows each held expert received, and the row tiles the
+    # grouped matmuls visited, summed over the expert layers
     expert_rows: Optional[jax.Array] = None
+    row_tiles: Optional[jax.Array] = None
 
     def take_beams(self, rows: jax.Array):
         """The cache with the flat suffix's rows ``rows`` (B*M,) kept."""
@@ -87,6 +89,7 @@ class LatentHistoryCache:
     sfx_r: jax.Array  # (L, B*M, Ls, rope_dim), or batched
     step: jax.Array  # () suffix column of the next token
     expert_rows: Optional[jax.Array] = None
+    row_tiles: Optional[jax.Array] = None
 
     def take_beams(self, rows: jax.Array):
         return dataclasses.replace(

@@ -18,7 +18,9 @@ expert.  Two dispatches:
   mesh axis; XLA inserts the dispatch all-to-alls.
 * :func:`moe_ffn_dropless` (serving): every (row, held expert) pair is
   computed — the pairs are sorted by expert and run through grouped
-  matmuls (``jax.lax.ragged_dot``), so nothing is dropped and the work
+  matmuls (:func:`repro.kernels.ops.grouped_matmul`: a Pallas kernel on
+  the TPU that keeps each expert's weights in VMEM over all its rows,
+  ``jax.lax.ragged_dot`` elsewhere), so nothing is dropped and the work
   follows the rows each expert really receives.
 """
 from __future__ import annotations
@@ -27,6 +29,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import MoEConfig
+from repro.kernels import ops
+from repro.kernels.grouped_matmul import TM, row_tiles
 from repro.models.layers import _he, swiglu, swiglu_init
 
 __all__ = ["moe_init", "moe_ffn", "moe_ffn_dropless", "route",
@@ -143,39 +147,55 @@ def _moe_ffn_single(params, x: jax.Array, cfg: MoEConfig):
 
 
 def moe_ffn_dropless(params, x: jax.Array, cfg: MoEConfig):
-    """x (..., D) -> (y (..., D), rows (n_experts,) int32): the served
-    expert layer, which computes every (row, held expert) pair.
+    """x (..., D) -> (y (..., D), rows (n_experts,) int32, row tiles ()
+    int32): the served expert layer, which computes every (row, held
+    expert) pair.
 
     The T*K routed pairs are sorted by held expert (pairs for experts held
     elsewhere sort last and are left out) and run through three grouped
-    matmuls; each pair's output is weighted by its router weight and summed
-    back into its row.  ``rows[e]`` counts the pairs held expert ``e``
-    received.  Scopes under the caller's: ``moe_router``, ``moe_experts``
-    (dispatch, grouped matmuls, combine) and ``moe_shared``.
+    matmuls (:func:`repro.kernels.ops.grouped_matmul`); each pair's
+    output is weighted by its router weight and summed back into its row.
+    ``rows[e]`` counts the pairs held expert ``e`` received.  On the
+    Pallas kernel the sort's index is padded to a multiple of its row tile
+    with pairs keyed "elsewhere", and the row tiles one grouped matmul
+    visits are counted (0 on the XLA path).  Scopes under the caller's:
+    ``moe_router``, ``moe_experts`` (dispatch, grouped matmuls, combine)
+    and ``moe_shared``.
     """
     D = x.shape[-1]
     flat = x.reshape(-1, D)
     T, E, K = flat.shape[0], cfg.n_experts, cfg.top_k
+    pallas = ops.resolve(None) == "pallas"
+    pad = -(T * K) % TM if pallas else 0
     with jax.named_scope("moe_router"):
         _, top_w, top_i = route(params, flat, cfg)
         local, held = _held(top_i, cfg)
         key = jnp.where(held, local, E).reshape(-1)  # (T*K,) E = elsewhere
+        if pad:
+            key = jnp.pad(key, (0, pad), constant_values=E)
         order = jnp.argsort(key, stable=True)
         sizes = jnp.bincount(key, length=E + 1)[:E].astype(jnp.int32)
+        tiles = row_tiles(sizes) if pallas else jnp.zeros((), jnp.int32)
     with jax.named_scope("moe_experts"):
-        xs = jnp.take(flat, order // K, axis=0)  # (T*K, D), by expert
-        h = jax.nn.silu(jax.lax.ragged_dot(xs, params["w1"], sizes)) \
-            * jax.lax.ragged_dot(xs, params["w3"], sizes)
-        y = jax.lax.ragged_dot(h, params["w2"], sizes)  # (T*K, D)
-        # rows past the held groups are not computed: zero them
+        # the padding's pairs gather past the rows (the fill value); they
+        # sort after every group and are never visited
+        xs = jnp.take(flat, order // K, axis=0)  # (T*K + pad, D), by expert
+
+        def gmm(lhs, w):
+            return ops.grouped_matmul(lhs, w, sizes)
+
+        h = jax.nn.silu(gmm(xs, params["w1"])) * gmm(xs, params["w3"])
+        y = gmm(h, params["w2"])  # (T*K + pad, D)
+        # rows past the held groups are not computed (the kernel leaves
+        # them unwritten, NaN possibly): zero them
         computed = jnp.take(key, order) < E
         y = jnp.where(computed[:, None], y, 0)
         inv = jnp.zeros_like(order).at[order].set(
-            jnp.arange(T * K, dtype=order.dtype))
-        y = jnp.take(y, inv, axis=0).reshape(T, K, D)
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        y = jnp.take(y, inv[: T * K], axis=0).reshape(T, K, D)
         w = jnp.where(held, top_w, 0.0).astype(y.dtype)
         out = jnp.einsum("tkd,tk->td", y, w)
     if "shared" in params:
         with jax.named_scope("moe_shared"):
             out = out + swiglu(params["shared"], flat)
-    return out.reshape(x.shape), sizes
+    return out.reshape(x.shape), sizes, tiles

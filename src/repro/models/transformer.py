@@ -210,7 +210,7 @@ def _layer_fwd(p, x, cfg: TransformerConfig, moe_layer: bool, q_offset=0,
         h = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
         aux = jnp.zeros((), jnp.float32)
         if moe_layer and dropless:
-            y, _ = moe_ffn_dropless(p["moe"], h, cfg.moe)
+            y, _, _ = moe_ffn_dropless(p["moe"], h, cfg.moe)
         elif moe_layer:
             y, aux = moe_ffn(p["moe"], h, cfg.moe)
         else:
@@ -735,9 +735,10 @@ def gr_decode_step(
 
 def _gr_decode(params, hist_a, hist_b, beam_a, beam_b, tokens, sid_step,
                cfg):
-    """:func:`gr_decode_step`, and the rows each held expert received
-    (``moe_ffn_dropless``'s count, summed over the expert layers), or
-    None for a decoder without experts."""
+    """:func:`gr_decode_step`, and ``moe_ffn_dropless``'s counts summed
+    over the expert layers (the rows each held expert received, and the
+    row tiles its grouped matmuls visited), or None for a decoder without
+    experts."""
     BM = tokens.shape[0]
     n, B, S = hist_a.shape[:3]
     M = BM // B
@@ -749,7 +750,7 @@ def _gr_decode(params, hist_a, hist_b, beam_a, beam_b, tokens, sid_step,
         x = jnp.take(params["emb"], tokens, axis=0)  # (BM, 1, D)
 
     def body(carry, inp):
-        x, sa, sb, rows = carry
+        x, sa, sb, rows, tiles = carry
         p, ha, hb, i = inp
         with jax.named_scope("qkv_proj"):
             h = rms_norm(p["ln_attn"], x, cfg.norm_eps)
@@ -760,22 +761,22 @@ def _gr_decode(params, hist_a, hist_b, beam_a, beam_b, tokens, sid_step,
         with jax.named_scope("ffn"):
             h = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
             if "moe" in p:
-                y, r = moe_ffn_dropless(p["moe"], h, cfg.moe)
-                rows = rows + r
+                y, r, t = moe_ffn_dropless(p["moe"], h, cfg.moe)
+                rows, tiles = rows + r, tiles + t
             else:
                 y = swiglu(p["ffn"], h)
             x = x + y
-        return (x, sa, sb, rows), None
+        return (x, sa, sb, rows, tiles), None
 
     # The suffixes ride in the carry, each layer's slot written in place:
     # as the scan's xs/ys every layer's slice would be sliced out and
     # stacked back, a copy each way per layer and level.  The histories
     # are only read, as the scan's xs.
     n_dense = cfg.moe.first_dense_layers if cfg.moe else n
-    rows = (jnp.zeros((cfg.moe.n_experts,), jnp.int32) if cfg.moe
-            else None)
+    rows, tiles = ((jnp.zeros((cfg.moe.n_experts,), jnp.int32),
+                    jnp.zeros((), jnp.int32)) if cfg.moe else (None, None))
     carry = (x, beam_a.reshape((n, B, M, Ls) + hist_a.shape[3:]),
-             beam_b.reshape((n, B, M, Ls) + hist_b.shape[3:]), rows)
+             beam_b.reshape((n, B, M, Ls) + hist_b.shape[3:]), rows, tiles)
     for group, lo, hi in (("dense_layers", 0, n_dense),
                           ("moe_layers", n_dense, n)):
         if hi > lo:
@@ -783,12 +784,12 @@ def _gr_decode(params, hist_a, hist_b, beam_a, beam_b, tokens, sid_step,
                 body, carry, (params[group], hist_a[lo:hi], hist_b[lo:hi],
                               jnp.arange(lo, hi)),
                 unroll=cfg.layer_unroll)
-    x, new_a, new_b, rows = carry
+    x, new_a, new_b, rows, tiles = carry
     with jax.named_scope("unembed"):
         x = rms_norm(params["final_norm"], x, cfg.norm_eps)
         logits = (x @ _unemb(params, cfg)).astype(jnp.float32)  # (BM, 1, V)
     return (logits, new_a.reshape(beam_a.shape), new_b.reshape(beam_b.shape),
-            rows)
+            rows, tiles)
 
 
 def _shared_history_step(params, cache, tokens, cfg):
@@ -799,12 +800,13 @@ def _shared_history_step(params, cache, tokens, cfg):
     else:
         names = ("sfx_k", "sfx_v")
         hist = (cache.hist_k, cache.hist_v)
-    logits, a, b, rows = _gr_decode(
+    logits, a, b, rows, tiles = _gr_decode(
         params, *hist, *(getattr(cache, f) for f in names), tokens,
         cache.step, cfg)
     new = dict(zip(names, (a, b)), step=cache.step + 1)
     if cache.expert_rows is not None:
         new["expert_rows"] = cache.expert_rows + rows
+        new["row_tiles"] = cache.row_tiles + tiles
     return logits, dataclasses.replace(cache, **new)
 
 
@@ -855,7 +857,7 @@ def decode_step(params, cache, tokens: jax.Array, cfg: TransformerConfig):
         with jax.named_scope("ffn"):
             h = rms_norm(p["ln_ffn"], x, cfg.norm_eps)
             if "moe" in p:
-                y, _ = moe_ffn_dropless(p["moe"], h, cfg.moe)
+                y, _, _ = moe_ffn_dropless(p["moe"], h, cfg.moe)
                 x = x + y
             else:
                 x = x + swiglu(p["ffn"], h)

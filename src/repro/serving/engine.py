@@ -319,9 +319,10 @@ class _EngineMetrics:
             self.recompiles.inc(
                 compiles, expected="true" if expected else "false")
 
-    def record_experts(self, rows, offset: int) -> None:
+    def record_experts(self, rows, offset: int, tiles: int = 0) -> None:
         """An expert decoder's batch: ``rows[e]`` rows routed to held
-        expert ``offset + e`` over the decode levels."""
+        expert ``offset + e`` over the decode levels, and ``tiles`` row
+        tiles its grouped matmuls visited there (none on the XLA path)."""
         r = self.registry
         routed = r.counter(
             "serving_moe_expert_rows_total",
@@ -329,6 +330,13 @@ class _EngineMetrics:
             "summed over the expert layers, by expert id")
         for e, n in enumerate(rows):
             routed.inc(int(n), expert=str(offset + e))
+        if tiles:
+            r.counter(
+                "serving_moe_row_tiles_total",
+                "row tiles one grouped matmul of each expert layer visited "
+                "in the decode levels, summed over the expert layers; times "
+                "the row tile over the routed pairs, the kernel's padded "
+                "compute").inc(int(tiles))
 
     def record_request(self, r: Request, t_admit: float, t_done: float, *,
                        t_first: Optional[float] = None,
@@ -515,7 +523,8 @@ class ServingEngine:
                 rows = self.retriever.expert_rows
                 if rows is not None:
                     self._m.record_experts(
-                        rows, self.retriever.cfg.moe.expert_offset)
+                        rows, self.retriever.cfg.moe.expert_offset,
+                        self.retriever.row_tiles)
                 for i, r in enumerate(batch):
                     results[r.rid] = {
                         "sids": beams[i],

@@ -61,8 +61,10 @@ class GenerativeRetriever:
         self.V = sid_vocab
         self.M = beam_size
         # expert decoders: the last batch's rows per held expert over its
-        # decode levels; None otherwise
+        # decode levels, and the row tiles its grouped matmuls visited
+        # there (0 on the XLA path); None otherwise
         self.expert_rows: Optional[np.ndarray] = None
+        self.row_tiles: Optional[int] = None
         # One jitted end-to-end retrieval step (prefill + L constrained beam
         # steps).  The policy rides in as a pytree ARGUMENT, so a registry
         # hot-swap (new leaf values, identical shapes + static metadata)
@@ -119,12 +121,13 @@ class GenerativeRetriever:
                 )
             cids = jnp.asarray(cids_np)
         with annotate("retrieve.dispatch"):
-            tokens, scores, rows = self._retrieve_jit(
+            tokens, scores, counts = self._retrieve_jit(
                 self.params, jnp.asarray(history), self.policy, cids
             )
         with annotate("retrieve.readback"):
-            if rows is not None:
-                self.expert_rows = np.asarray(rows)
+            if counts is not None:
+                self.expert_rows = np.asarray(counts[0])
+                self.row_tiles = int(counts[1])
             return np.asarray(tokens), np.asarray(scores)
 
     def compile_step(self, batch: int, prompt_width: int):
@@ -137,9 +140,10 @@ class GenerativeRetriever:
             self.params, hist, self.policy, None).compile()
 
     def _retrieve_impl(self, params, history, policy, constraint_ids):
-        """(tokens (B, M, L), scores (B, M), expert rows): the last is the
-        rows each held expert received over the decode levels, for an
-        expert decoder, else None."""
+        """(tokens (B, M, L), scores (B, M), expert counts): the last is,
+        for an expert decoder, the rows each held expert received over the
+        decode levels and the row tiles the grouped matmuls visited there,
+        else None."""
         B, S = history.shape
         M, cfg = self.M, self.cfg
         Ls = self.L - 1  # a suffix column for each decode level after prefill
@@ -163,20 +167,21 @@ class GenerativeRetriever:
             return jnp.zeros((cfg.n_layers, B * M, Ls) + a.shape[3:],
                              a.dtype)
 
-        rows = (jnp.zeros((cfg.moe.n_experts,), jnp.int32)
-                if cfg.moe else None)
+        rows, tiles = ((jnp.zeros((cfg.moe.n_experts,), jnp.int32),
+                        jnp.zeros((), jnp.int32)) if cfg.moe
+                       else (None, None))
         step = jnp.zeros((), jnp.int32)
         if isinstance(cache, kv_lib.MLACache):
             cache = kv_lib.LatentHistoryCache(
                 hist_c=cache.c_kv, hist_r=cache.k_rope,
                 sfx_c=empty_suffix(cache.c_kv),
                 sfx_r=empty_suffix(cache.k_rope), step=step,
-                expert_rows=rows)
+                expert_rows=rows, row_tiles=tiles)
         else:
             sfx = empty_suffix(cache.k)
             cache = kv_lib.SharedHistoryCache(
                 hist_k=cache.k, hist_v=cache.v, sfx_k=sfx, sfx_v=sfx,
-                step=step, expert_rows=rows)
+                step=step, expert_rows=rows, row_tiles=tiles)
 
         def logits_fn(c, last_tokens, step):
             logits, c = transformer.decode_step(
@@ -193,4 +198,6 @@ class GenerativeRetriever:
             first_logits=pre_logits[:, 0, : self.V],
             constraint_ids=constraint_ids,
         )
-        return state.tokens, state.scores, cache.expert_rows
+        counts = (None if cache.expert_rows is None
+                  else (cache.expert_rows, cache.row_tiles))
+        return state.tokens, state.scores, counts

@@ -1,7 +1,9 @@
 """The expert layer that holds a share of the experts, its routing rule,
 and DeepSeek-V2's YaRN rotary scaling, against plain float32 formulas."""
 import dataclasses
+import functools
 import math
+import types
 
 import jax
 import jax.numpy as jnp
@@ -10,6 +12,8 @@ import pytest
 
 from repro.configs import get_bundle
 from repro.configs.base import MoEConfig, YarnScaling
+from repro.kernels import ops
+from repro.kernels.grouped_matmul import row_tiles
 from repro.models import moe
 from repro.models.layers import apply_rope, rope_frequencies, swiglu
 
@@ -53,7 +57,7 @@ def test_expert_shares_add_up_to_the_uncut_layer():
     """8 chips hold 2 experts each at their offsets; with the shared
     expert counted once, their parts add up to the whole layer."""
     p, x = _layer()
-    whole, rows = moe.moe_ffn_dropless(p, x, UNCUT)
+    whole, rows, _ = moe.moe_ffn_dropless(p, x, UNCUT)
     np.testing.assert_allclose(whole, _reference(p, x, K, False),
                                rtol=1e-4, atol=1e-5)
     parts = []
@@ -62,7 +66,7 @@ def test_expert_shares_add_up_to_the_uncut_layer():
                                   expert_offset=2 * chip)
         share = dict(p, **{n: p[n][2 * chip:2 * chip + 2]
                            for n in ("w1", "w3", "w2")})
-        y, r = moe.moe_ffn_dropless(share, x, cfg)
+        y, r, _ = moe.moe_ffn_dropless(share, x, cfg)
         np.testing.assert_allclose(
             y, _reference(share, x, K, False, range(2 * chip, 2 * chip + 2)),
             rtol=1e-4, atol=1e-5)
@@ -74,12 +78,45 @@ def test_expert_shares_add_up_to_the_uncut_layer():
     assert rows.shape == (E,) and int(rows.sum()) == T * K
 
 
+def pallas_ops():
+    """``repro.kernels.ops`` as the expert layer sees it on a TPU: the
+    grouped matmul on the Pallas kernel (interpret mode here)."""
+    return types.SimpleNamespace(
+        resolve=lambda impl: "pallas",
+        grouped_matmul=functools.partial(ops.grouped_matmul, impl="pallas"))
+
+
+@pytest.mark.parametrize("chip", [None, 0, 3, 7],
+                         ids=["uncut", "held-0", "held-3", "held-7"])
+def test_expert_layer_on_the_kernel_matches_the_formula(chip, monkeypatch):
+    """The served layer on the Pallas grouped matmul (interpret mode) gives
+    the equations' output and the XLA path's rows, uncut or holding a
+    share of the experts, and counts the row tiles of its groups."""
+    p, x = _layer(seed=11)
+    cfg, held = UNCUT, range(E)
+    if chip is not None:
+        held = range(2 * chip, 2 * chip + 2)
+        cfg = dataclasses.replace(UNCUT, n_experts=2, n_routed=E,
+                                  expert_offset=2 * chip)
+        p = dict(p, **{n: p[n][held.start:held.stop]
+                       for n in ("w1", "w3", "w2")})
+    _, want_rows, no_tiles = moe.moe_ffn_dropless(p, x, cfg)
+    assert int(no_tiles) == 0  # the XLA path counts none
+    monkeypatch.setattr(moe, "ops", pallas_ops())
+    y, rows, tiles = moe.moe_ffn_dropless(p, x, cfg)
+    np.testing.assert_allclose(y, _reference(p, x, K, False, held),
+                               rtol=1e-4, atol=1e-5)
+    np.testing.assert_array_equal(rows, want_rows)
+    assert int(tiles) == int(row_tiles(rows))
+    assert int(tiles) >= np.count_nonzero(np.asarray(rows))
+
+
 def test_renormalised_shares_add_up_to_the_uncut_layer():
     """Mixtral's rule (top-k weights renormalised, no shared expert): 4
     chips of 4 experts each add up to the whole layer."""
     cfg = MoEConfig(n_experts=E, top_k=2, d_expert=8)
     p, x = _layer(cfg, seed=5)
-    whole, _ = moe.moe_ffn_dropless(p, x, cfg)
+    whole, _, _ = moe.moe_ffn_dropless(p, x, cfg)
     np.testing.assert_allclose(
         whole, _reference(dict(p, shared=_no_shared(cfg)), x, 2, True),
         rtol=1e-4, atol=1e-5)
@@ -98,7 +135,7 @@ def test_held_expert_rows_count_the_routed_pairs():
                               expert_offset=4)
     p, x = _layer(UNCUT, seed=6)
     p = dict(p, **{n: p[n][4:8] for n in ("w1", "w3", "w2")})
-    _, rows = moe.moe_ffn_dropless(p, x, cfg)
+    _, rows, _ = moe.moe_ffn_dropless(p, x, cfg)
     _, _, ids = moe.route(p, x, cfg)
     want = np.bincount(np.asarray(ids).reshape(-1), minlength=E)[4:8]
     np.testing.assert_array_equal(rows, want)
@@ -112,7 +149,7 @@ def test_dropless_when_every_row_routes_to_one_held_expert():
     p, x = _layer(cfg, seed=3)
     x = jnp.abs(x) + 0.1
     p = dict(p, router=p["router"].at[:, 0].set(10.0))
-    y, rows = moe.moe_ffn_dropless(p, x, cfg)
+    y, rows, _ = moe.moe_ffn_dropless(p, x, cfg)
     assert rows.shape == (4,) and int(rows[0]) == T
     want = _reference(dict(p, shared=_no_shared(cfg)), x, 2, True,
                       range(4))

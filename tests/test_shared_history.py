@@ -160,3 +160,46 @@ def test_engine_counts_rows_per_held_expert():
     assert {dict(k)["expert"] for k, _ in routed.labeled()} == {
         str(e) for e in range(held)}
     assert retr.expert_rows.shape == (held,)
+
+
+def test_engine_counts_row_tiles_of_the_grouped_matmul_kernel(monkeypatch):
+    """With the expert layers on the Pallas grouped matmul (interpret
+    mode), the engine counts the row tiles it visited in the decode
+    levels: the retriever's last batch, at least one tile in each expert
+    layer of each level, with the same routing as the XLA path.  The XLA
+    path counts none."""
+    import functools
+    import types
+
+    from repro.kernels import ops
+    from repro.models import moe
+    from repro.serving.engine import RequestQueue, ServingEngine
+
+    cfg, params = _decoder("deepseek-v2-lite-16b")
+    rng = np.random.default_rng(5)
+    tm = TransitionMatrix.from_sids(make_sids(rng, 200, V, L), V, dense_d=2)
+    hist = [rng.integers(0, V, S).astype(np.int32) for _ in range(B)]
+
+    def serve():
+        retr = GenerativeRetriever(params, cfg, DecodePolicy.static(tm),
+                                   sid_length=L, sid_vocab=V, beam_size=M)
+        eng = ServingEngine(params, cfg, B, 2 * S, retriever=retr)
+        q = RequestQueue()
+        for h in hist:
+            q.submit(h, L)
+        res = eng.serve(q)
+        assert len(res) == B
+        return retr, eng.metrics
+
+    xla, m_xla = serve()
+    assert xla.row_tiles == 0
+    assert "serving_moe_row_tiles_total" not in m_xla.render_prometheus()
+    monkeypatch.setattr(moe, "ops", types.SimpleNamespace(
+        resolve=lambda impl: "pallas",
+        grouped_matmul=functools.partial(ops.grouped_matmul, impl="pallas")))
+    retr, m = serve()
+    np.testing.assert_array_equal(retr.expert_rows, xla.expert_rows)
+    tiles = m.counter("serving_moe_row_tiles_total").total()
+    assert tiles == retr.row_tiles > 0
+    per_layer_level = (L - 1) * (cfg.n_layers - cfg.moe.first_dense_layers)
+    assert tiles >= per_layer_level  # every layer routes some rows here

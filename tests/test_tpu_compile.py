@@ -133,3 +133,89 @@ def test_static_gr_step_compiles_and_fits_v5e(one_chip, no_persistent_cache,
     assert geo.batch == 2 and total < 16 * GIB, total / GIB
     assert ma.temp_size_in_bytes < 3 * GIB, ma.temp_size_in_bytes / GIB
     assert "bf16[26,140,265,8,128]" not in compiled.as_text()
+
+
+def _expert_layer(name):
+    """(MoEConfig, d_model, rows) of a served expert layer: DeepSeek-V2-
+    Lite's with 8 of its 64 experts held, at ``v2lite-ep8.bulk``'s decode
+    level (16 requests x 70 beams) and prefill (16 x 256 history tokens),
+    and Mixtral-8x7B's, all held, at a decode level of the same rows."""
+    from repro.configs.base import MoEConfig
+
+    v2lite = MoEConfig(n_experts=8, n_routed=64, top_k=6, d_expert=1408,
+                       norm_topk_prob=False, n_shared=2, d_shared=2816)
+    mixtral = MoEConfig(n_experts=8, top_k=2, d_expert=14336)
+    return {"v2lite-level": (v2lite, 2048, 1120),
+            "v2lite-prefill": (v2lite, 2048, 4096),
+            "mixtral-level": (mixtral, 4096, 1120)}[name]
+
+
+@pytest.mark.parametrize("name", ["v2lite-level", "v2lite-prefill",
+                                  "mixtral-level"])
+def test_expert_layer_kernel_compiles_for_v5e(one_chip, no_persistent_cache,
+                                              monkeypatch, name):
+    """The served expert layer compiles its grouped matmuls as the Pallas
+    kernel with the tiling the shape picks (DeepSeek-V2-Lite: whole
+    2048x1408 slabs; Mixtral: the widest tn that fits), and each kernel
+    keeps the caller's scope path, so the benchmark's reader of the decode
+    levels' experts counts it."""
+    import re
+
+    from bench.metrics._experts import EXPERTS
+    from repro.kernels.grouped_matmul import TM, tiling
+    from repro.models import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, d, rows = _expert_layer(name)
+    if name.startswith("v2lite"):
+        assert tiling(d, cfg.d_expert, 2) == (TM, d, cfg.d_expert)
+        assert tiling(cfg.d_expert, d, 2) == (TM, cfg.d_expert, d)
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(sds, jax.eval_shape(
+        lambda k: moe.moe_init(k, d, cfg), jax.random.key(0)))
+    x = jax.ShapeDtypeStruct((rows, d), jnp.bfloat16, sharding=one_chip)
+
+    def level(p, x):
+        with jax.named_scope("decode_logits_L1"):
+            return moe.moe_ffn_dropless(p, x, cfg)
+
+    text = jax.jit(level).lower(params, x).compile().as_text()
+    kernels = [re.search(r'op_name="([^"]*)"', line).group(1)
+               for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 3, kernels  # w1, w3, w2
+    assert all(EXPERTS.search(k) for k in kernels), kernels
+
+
+@pytest.mark.parametrize("tiles", [
+    (128, 2048, 1408, 2048), (128, 1408, 2048, 1408),
+    (128, 2048, 1664, 2048), (128, 14336, 128, 14336),
+    (128, 2560, 1408, 2560), (128, 4096, 896, 4096),
+    (128, 16384, 128, 16384)])
+def test_grouped_matmul_vmem_budget_agrees_with_the_compiler(
+        one_chip, no_persistent_cache, tiles):
+    """The tiling rule's VMEM count admits the (tm, tk, tn) tilings of a
+    K-deep call that the compiler accepts, the tightest among them, and
+    turns away those it refuses for VMEM."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from repro.kernels.grouped_matmul import VMEM_BUDGET, _vmem_bytes
+
+    tm, tk, tn, k = tiles
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sds((8 * tm, k), jnp.bfloat16), sds((8, k, tn), jnp.bfloat16),
+            sds((8,), jnp.int32))
+    compile_ = jax.jit(lambda a, b, s: gmm(
+        a, b, s, preferred_element_type=jnp.bfloat16, tiling=tiles[:3],
+        interpret=False)).lower(*args).compile
+    if _vmem_bytes(tm, tk, tn, 2) <= VMEM_BUDGET:
+        assert "tpu_custom_call" in compile_().as_text()
+    else:
+        with pytest.raises(Exception, match="vmem"):
+            compile_()
